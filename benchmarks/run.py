@@ -68,6 +68,19 @@ def main(argv=None) -> None:
                          "(benchmarks/serve_bench.py; spawns 1-device and "
                          "forced-8-device subprocesses)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache  # noqa: PLC0415
+
+    use_compile_cache()
+    serve_rows: list = []
+    serve_metrics: dict = {}
+    if args.serve:
+        # first, while this process has not touched a device: the
+        # 1-device cell runs in its own process, which on a chip host
+        # must be the only one holding the chip
+        from benchmarks import serve_bench  # noqa: PLC0415
+
+        serve_rows, serve_metrics = serve_bench.bench_rows_and_metrics(
+            smoke=args.smoke)
 
     from benchmarks import (  # noqa: PLC0415
         fig4_resnet_layers,
@@ -97,13 +110,7 @@ def main(argv=None) -> None:
             rows += mrows
             layer_rows += mlayers
         layer_rows = _dedupe_layers(layer_rows)
-    serve_metrics: dict = {}
-    if args.serve:
-        from benchmarks import serve_bench  # noqa: PLC0415
-
-        srows, serve_metrics = serve_bench.bench_rows_and_metrics(
-            smoke=args.smoke)
-        rows += srows
+    rows += serve_rows
 
     print("\nname,us_per_call,derived")
     for name, us, derived in rows:

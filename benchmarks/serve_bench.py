@@ -68,8 +68,11 @@ def _child(devices: int, smoke: bool) -> None:
 
     from repro import api, compat
     from repro.configs import get_reduced
+    from repro.launch.compile_cache import use_compile_cache
     from repro.models.transformer import LM
     from repro.serving.engine import Request, ServeEngine, ShardedServeEngine
+
+    use_compile_cache()
 
     slots, prefill_len, chunk = 4, 16, 8
     requests = 6 if smoke else 24

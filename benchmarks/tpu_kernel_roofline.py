@@ -2,8 +2,9 @@
 matmul on TPU v5e constants, over the paper's CNN GEMMs + transformer
 projection GEMMs — for both value families (bf16 and the int8 QNMWeight
 path, which streams one byte per kept value + a f32 scale per output
-channel). Also times the interpret-mode kernels vs their oracles on one
-shape (correctness + a real measured number for the CSV).
+channel). Also times the kernels vs their oracles on one shape
+(compiled on a TPU, interpreted on the CPU; correctness + a measured
+number for the CSV).
 
 ``kernel_records()`` returns the machine-readable per-kernel rows that
 ``benchmarks/run.py`` writes into BENCH_results.json.
@@ -22,6 +23,7 @@ from repro.core.cost_model import (
 )
 from repro.core.sparsity import NMConfig, compress_nm, random_nm_matrix
 from repro.kernels import autotune
+from repro.kernels.backend import interpret_for
 from repro.kernels.indexmac.kernel import nm_spmm_pallas, nm_spmm_pallas_q
 from repro.kernels.indexmac.ref import nm_matmul_q_ref, nm_matmul_ref
 
@@ -103,7 +105,7 @@ def timed_correctness(cfgs=(NMConfig(2, 4), NMConfig(1, 4))):
         y_ref = nm_matmul_ref(x, vals, idx, cfg)
         f = lambda: nm_spmm_pallas(  # noqa: E731
             x, vals, idx, cfg=cfg, block_m=bm, block_n=bn, block_k=bk,
-            interpret=True)
+            interpret=interpret_for("tpu"))
         y = f().block_until_ready()
         us = best_us(f, repeats=3)
         err = float(jnp.abs(y - y_ref).max())
@@ -119,7 +121,7 @@ def timed_correctness(cfgs=(NMConfig(2, 4), NMConfig(1, 4))):
         yq_ref = nm_matmul_q_ref(x, qvals, idx, scales, cfg)
         fq = lambda: nm_spmm_pallas_q(  # noqa: E731
             x, qvals, idx, scales, cfg=cfg, block_m=qbm, block_n=qbn,
-            block_k=qbk, interpret=True)
+            block_k=qbk, interpret=interpret_for("tpu"))
         yq = fq().block_until_ready()
         us_q = best_us(fq, repeats=3)
         err_q = float(jnp.abs(yq - yq_ref).max())

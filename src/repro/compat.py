@@ -1,280 +1,90 @@
-"""Single resolution point for version-drifted JAX APIs.
+"""Single home for the JAX APIs the rest of the repo must spell one way.
 
-The repo targets the current JAX while staying runnable on the 0.4.x line
-(the oldest toolchain we CI against). Every API whose name or home moved
-between 0.4.x and 0.5.x is resolved HERE, once, at import time — the rest
-of the codebase imports from ``repro.compat`` and never touches the
-drifting names directly (enforced by tests/test_compat.py).
+The repo supports the installed JAX line (0.9). The names below are
+resolved HERE and the rest of the codebase imports them from
+``repro.compat`` (enforced by tests/test_compat.py), so a later move of
+any of them is a one-file change.
 
-Resolved surface:
-
-* ``get_abstract_mesh()`` — 0.5.x ``jax.sharding.get_abstract_mesh``;
-  on 0.4.x falls back to the thread-local physical mesh's abstract view.
-  Returns ``None`` when no mesh context is active (callers treat that as
+* ``get_abstract_mesh()`` / ``active_mesh()`` — the active abstract mesh,
+  or ``None`` when no mesh context is active (callers treat that as
   "hints are no-ops").
-* ``set_mesh(mesh)`` — context manager. 0.5.x ``jax.set_mesh`` /
-  ``jax.sharding.use_mesh``; on 0.4.x ``with mesh:`` (which is what feeds
-  the 0.4.x ``get_abstract_mesh`` fallback above, so the pair is
-  self-consistent on both lines).
-* ``make_mesh(shape, axes)`` — ``jax.make_mesh`` where present, else
-  built from ``mesh_utils.create_device_mesh``.
-* ``manual_axis_in(mesh)`` — True when any mesh axis is Manual
-  (inside shard_map). 0.4.x meshes have no axis_types: always False.
-* ``tpu_compiler_params(**kw)`` — Pallas-TPU compiler params object:
-  ``pltpu.CompilerParams`` (>= 0.5) or ``pltpu.TPUCompilerParams``
-  (0.4.x), whichever the installed Pallas exports.
+* ``set_mesh(mesh)`` — context manager activating ``mesh``.
+* ``make_mesh(shape, axes)`` — every axis ``Auto``. On JAX
+  0.9 ``jax.make_mesh`` defaults to ``Explicit`` axes, under which
+  ``with_sharding_constraint`` asserts the sharding instead of hinting
+  it; the repo's sharding hints and shard_map engines are written for
+  compiler-propagated (Auto) shardings.
+* ``manual_axis_in(mesh)`` — True when any mesh axis is Manual (inside
+  shard_map).
+* ``shard_map`` — ``jax.shard_map``.
+* ``cost_analysis(compiled)`` — ``Compiled.cost_analysis()`` as a dict.
+* ``tpu_compiler_params(**kw)`` — ``pltpu.CompilerParams``; an unknown
+  field raises.
 * ``register_dataclass(cls, data_fields, meta_fields)`` — pytree
-  registration for dataclasses (``NMWeight``): native
-  ``jax.tree_util.register_dataclass`` where present (keyword spelling
-  drifted across lines), else built from
-  ``register_pytree_with_keys``.
-* ``resolved()`` — {name: "how it resolved"} for diagnostics and the
-  compat regression test.
+  registration for dataclasses (``NMWeight``).
 """
 from __future__ import annotations
 
-import contextlib
-from typing import Any, Optional, Sequence
+from typing import Any, Sequence
 
 import jax
-
-_RESOLVED: dict[str, str] = {}
-
-
-def jax_version() -> tuple[int, ...]:
-    return tuple(int(p) for p in jax.__version__.split(".")[:3])
-
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import AxisType
 
 # ---------------------------------------------------------------------------
 # mesh context
 # ---------------------------------------------------------------------------
 
-if hasattr(jax.sharding, "get_abstract_mesh"):
-    _RESOLVED["get_abstract_mesh"] = "jax.sharding.get_abstract_mesh"
 
-    def get_abstract_mesh():
-        """Active (abstract) mesh, or None outside any mesh context."""
-        m = jax.sharding.get_abstract_mesh()
-        return m if m is not None and m.shape_tuple else None
-
-else:  # 0.4.x: the active mesh lives in the thread-local resource env
-    _RESOLVED["get_abstract_mesh"] = "jax._src.mesh.thread_resources"
-
-    def get_abstract_mesh():
-        """Active mesh, or None outside any mesh context.
-
-        Returns the *physical* mesh on this line: 0.4.x shard_map and
-        with_sharding_constraint are only fully supported against it
-        (AbstractMesh existed but plumbing it through jit trips XLA's
-        sharding-remover pass).
-        """
-        from jax._src import mesh as mesh_lib
-
-        pm = mesh_lib.thread_resources.env.physical_mesh
-        if pm is None or pm.empty:
-            return None
-        return pm
+def get_abstract_mesh():
+    """Active (abstract) mesh, or None outside any mesh context."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m is not None and m.shape_tuple else None
 
 
-if hasattr(jax, "set_mesh"):
-    _RESOLVED["set_mesh"] = "jax.set_mesh"
-    _set_mesh_impl = jax.set_mesh
-elif hasattr(jax.sharding, "use_mesh"):
-    _RESOLVED["set_mesh"] = "jax.sharding.use_mesh"
-    _set_mesh_impl = jax.sharding.use_mesh
-else:
-    _RESOLVED["set_mesh"] = "with-mesh-context (0.4.x)"
-
-    @contextlib.contextmanager
-    def _set_mesh_impl(mesh):
-        with mesh:
-            yield
+# call sites outside this module use `compat.active_mesh()`
+active_mesh = get_abstract_mesh
 
 
 def set_mesh(mesh):
     """Context manager activating `mesh` so shard hints see it."""
-    return _set_mesh_impl(mesh)
+    return jax.set_mesh(mesh)
 
 
-if hasattr(jax, "make_mesh"):
-    _RESOLVED["make_mesh"] = "jax.make_mesh"
-
-    def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-        return jax.make_mesh(tuple(shape), tuple(axes))
-
-else:
-    _RESOLVED["make_mesh"] = "mesh_utils.create_device_mesh"
-
-    def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-        from jax.experimental import mesh_utils
-
-        devices = mesh_utils.create_device_mesh(tuple(shape))
-        return jax.sharding.Mesh(devices, tuple(axes))
-
-
-# Alias without the drifted name: call sites outside this module use
-# `compat.active_mesh()` so a grep for the moved API hits only this file.
-def active_mesh():
-    return get_abstract_mesh()
+def make_mesh(shape: Sequence[int], axes: Sequence[str]):
+    """A mesh with every axis ``Auto`` (see module docstring)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def manual_axis_in(mesh: Any) -> bool:
-    """True iff any axis of `mesh` is Manual (inside a shard_map region).
+    """True iff any axis of `mesh` is Manual (inside a shard_map region)."""
+    return any(t == AxisType.Manual for t in mesh.axis_types)
 
-    0.5.x meshes carry axis_types; on 0.4.x shard_map instead binds the
-    mesh axes into the tracing axis env, so "any mesh axis currently
-    bound" is the equivalent signal. Missing either detection would let
-    shard hints emit with_sharding_constraint inside manual regions —
-    which trips XLA's sharding-remover on the 0.4.x line.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    types = getattr(mesh, "axis_types", None)
-    if axis_type is not None and types is not None:
-        try:
-            return any(t == axis_type.Manual for t in types)
-        except TypeError:
-            return False
-    try:
-        from jax._src import core as _core
 
-        bound = _core.get_axis_env().axis_sizes
-    except (ImportError, AttributeError):
-        return False
-    return any(a in bound for a in mesh.axis_names)
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 # ---------------------------------------------------------------------------
-# shard_map
+# compiled-artifact introspection, Pallas params, pytree registration
 # ---------------------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):
-    _RESOLVED["shard_map"] = "jax.shard_map"
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-
-else:  # 0.4.x: experimental home, and the check kwarg is `check_rep`
-    _RESOLVED["shard_map"] = "jax.experimental.shard_map"
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma,
-        )
-
-
-# ---------------------------------------------------------------------------
-# compiled-artifact introspection
-# ---------------------------------------------------------------------------
-
-_RESOLVED["cost_analysis"] = "normalized (dict | [dict])"
 
 
 def cost_analysis(compiled) -> dict:
-    """`Compiled.cost_analysis()` as a flat dict.
-
-    0.4.x returns a one-element list of dicts (per device program), newer
-    JAX returns the dict itself.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """`Compiled.cost_analysis()` as a flat dict."""
+    return dict(compiled.cost_analysis() or {})
 
 
-# ---------------------------------------------------------------------------
-# Pallas TPU compiler params
-# ---------------------------------------------------------------------------
+def tpu_compiler_params(**kwargs) -> Any:
+    """``pltpu.CompilerParams(**kwargs)``."""
+    return pltpu.CompilerParams(**kwargs)
 
 
-def _resolve_tpu_params_cls():
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-    except ImportError:  # pallas not available at all
-        return None, "unavailable"
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            return cls, f"pltpu.{name}"
-    return None, "unavailable"
-
-
-_TPU_PARAMS_CLS, _how = _resolve_tpu_params_cls()
-_RESOLVED["tpu_compiler_params"] = _how
-
-
-def tpu_compiler_params(**kwargs) -> Optional[Any]:
-    """Pallas-TPU compiler params under whichever name this JAX exports.
-
-    Drops kwargs the installed class doesn't know (field sets drifted
-    too); returns None when Pallas TPU params are unavailable, which
-    ``pallas_call`` accepts as "no params".
-    """
-    if _TPU_PARAMS_CLS is None:
-        return None
-    try:
-        return _TPU_PARAMS_CLS(**kwargs)
-    except TypeError:
-        import dataclasses
-
-        known = {f.name for f in dataclasses.fields(_TPU_PARAMS_CLS)}
-        return _TPU_PARAMS_CLS(
-            **{k: v for k, v in kwargs.items() if k in known}
-        )
-
-
-# ---------------------------------------------------------------------------
-# dataclass pytree registration
-# ---------------------------------------------------------------------------
-
-if hasattr(jax.tree_util, "register_dataclass"):
-    _RESOLVED["register_dataclass"] = "jax.tree_util.register_dataclass"
-
-    def register_dataclass(cls, data_fields: Sequence[str],
-                           meta_fields: Sequence[str]):
-        """Register ``cls`` as a pytree: data_fields are leaves (with
-        GetAttrKey paths), meta_fields are static treedef aux data."""
-        return jax.tree_util.register_dataclass(
-            cls, list(data_fields), list(meta_fields)
-        )
-
-else:  # very old lines: build it from register_pytree_with_keys
-    _RESOLVED["register_dataclass"] = "register_pytree_with_keys"
-
-    def register_dataclass(cls, data_fields: Sequence[str],
-                           meta_fields: Sequence[str]):
-        import dataclasses
-
-        from jax.tree_util import GetAttrKey, register_pytree_with_keys
-
-        data_fields = tuple(data_fields)
-        meta_fields = tuple(meta_fields)
-
-        def flatten_with_keys(obj):
-            children = [(GetAttrKey(f), getattr(obj, f))
-                        for f in data_fields]
-            aux = tuple(getattr(obj, f) for f in meta_fields)
-            return children, aux
-
-        def unflatten(aux, children):
-            kw = dict(zip(data_fields, children))
-            kw.update(zip(meta_fields, aux))
-            return cls(**kw)
-
-        def flatten(obj):
-            return ([getattr(obj, f) for f in data_fields],
-                    tuple(getattr(obj, f) for f in meta_fields))
-
-        register_pytree_with_keys(cls, flatten_with_keys, unflatten, flatten)
-        return dataclasses.dataclass(cls) if not dataclasses.is_dataclass(
-            cls) else cls
-
-
-def resolved() -> dict[str, str]:
-    """How each drifted API resolved on the installed JAX (diagnostics)."""
-    return dict(_RESOLVED)
+def register_dataclass(cls, data_fields: Sequence[str],
+                       meta_fields: Sequence[str]):
+    """Register ``cls`` as a pytree: data_fields are leaves (with
+    GetAttrKey paths), meta_fields are static treedef aux data."""
+    return jax.tree_util.register_dataclass(
+        cls, list(data_fields), list(meta_fields))

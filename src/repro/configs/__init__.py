@@ -1,11 +1,14 @@
 """Architecture registry: 10 assigned archs, full + reduced (smoke) configs.
 
-get_config(name, sparse=True)  -> full-size ModelConfig (dry-run only)
+get_config(name, sparse=True)  -> full-size ModelConfig at published widths
 get_reduced(name)              -> CPU-runnable reduced config, same family
                                   structure (pattern, mixers, MoE, enc-dec)
+cut_layers(cfg, n)             -> cfg keeping its first n decoder layers,
+                                  every width unchanged
 """
 from __future__ import annotations
 
+import dataclasses
 import importlib
 
 from repro.configs.base import (  # noqa: F401
@@ -72,6 +75,24 @@ def get_config(name: str, sparse: bool = True) -> ModelConfig:
 
 def get_reduced(name: str, sparse: bool = True) -> ModelConfig:
     return _mod(name).reduced(sparse=sparse)
+
+
+def cut_layers(cfg: ModelConfig, n: int) -> ModelConfig:
+    """``cfg`` cut to its first ``n`` decoder layers (whole repeating
+    periods), every width as published — how a full config is sized to
+    one chip."""
+    plan, left = [], n
+    for entry, rep in cfg.plan:
+        period = len(entry) if isinstance(entry, tuple) else 1
+        take = min(rep, left // period)
+        if take:
+            plan.append((entry, take))
+            left -= take * period
+    if left or not plan:
+        raise ValueError(
+            f"{cfg.name}: cannot cut to {n} layers in whole periods of "
+            f"its plan")
+    return dataclasses.replace(cfg, plan=tuple(plan))
 
 
 def runnable_shapes(name: str) -> list[str]:

@@ -83,24 +83,51 @@ def _move_axis_last(x: jax.Array, axis: int) -> jax.Array:
     return jnp.moveaxis(x, axis, -1)
 
 
-def prune_mask_nm(w: jax.Array, cfg: NMConfig, axis: int = 0) -> jax.Array:
-    """Magnitude-based N:M mask: keep the top-``n`` |w| in every ``m``-block.
+def _block_slots(w: jax.Array, m: int, axis: int) -> list[jax.Array]:
+    """The m positions of every M-block along ``axis`` as m arrays:
+    ``out[j] = w[..., j::m, ...]``. Working on these instead of a
+    ``(..., blocks, m)`` view keeps M off the minor axis, which a TPU
+    would pad to 128 lanes (32x the bytes for M = 4)."""
+    return [jax.lax.slice_in_dim(w, j, None, stride=m, axis=axis)
+            for j in range(m)]
 
-    Returns a boolean mask with w's shape. Deterministic (ties broken by
-    position via stable argsort on (-|w|, position)).
-    """
+
+def _interleave(parts: Sequence[jax.Array], axis: int) -> jax.Array:
+    """Inverse of :func:`_block_slots` for k parts: position j of every
+    k-block along ``axis`` comes from ``parts[j]``."""
+    out = jnp.stack(parts, axis=axis + 1)
+    shape = list(parts[0].shape)
+    shape[axis] *= len(parts)
+    return out.reshape(shape)
+
+
+def _check_axis(w, cfg: NMConfig, axis: int) -> int:
+    axis = axis % w.ndim
     if w.shape[axis] % cfg.m != 0:
         raise ValueError(
             f"axis {axis} size {w.shape[axis]} not divisible by M={cfg.m}"
         )
-    wl = _move_axis_last(w, axis)
-    blocks = wl.reshape(*wl.shape[:-1], wl.shape[-1] // cfg.m, cfg.m)
-    # rank within each block by |value| descending; keep rank < n
-    order = jnp.argsort(-jnp.abs(blocks), axis=-1, stable=True)
-    ranks = jnp.argsort(order, axis=-1, stable=True)
-    mask = ranks < cfg.n
-    mask = mask.reshape(*wl.shape[:-1], wl.shape[-1])
-    return jnp.moveaxis(mask, -1, axis)
+    return axis
+
+
+def prune_mask_nm(w: jax.Array, cfg: NMConfig, axis: int = 0) -> jax.Array:
+    """Magnitude-based N:M mask: keep the top-``n`` |w| in every ``m``-block.
+
+    Returns a boolean mask with w's shape. Deterministic: ties are broken
+    by position (the lower position wins), as a stable sort on
+    (-|w|, position) would rank them.
+    """
+    axis = _check_axis(w, cfg, axis)
+    a = [jnp.abs(x) for x in _block_slots(w, cfg.m, axis)]
+    keep = []
+    for j in range(cfg.m):
+        # rank of position j in its block: larger magnitudes, and equal
+        # magnitudes at lower positions, come first
+        rank = sum(((a[i] > a[j]) | (a[i] == a[j]) if i < j
+                    else a[i] > a[j]).astype(jnp.int32)
+                   for i in range(cfg.m) if i != j)
+        keep.append(rank < cfg.n)
+    return _interleave(keep, axis)
 
 
 def apply_mask(w: jax.Array, mask: jax.Array) -> jax.Array:
@@ -116,28 +143,29 @@ def compress_nm(w: jax.Array, cfg: NMConfig, axis: int = 0):
 
     Within each block the kept entries are ordered by ascending position
     (paper Fig. 1b stores them left-to-right). Blocks with fewer than n
-    non-zeros are padded with value 0 / idx of the last kept position (a
-    zero value makes the index a don't-care).
+    non-zeros fill the remaining slots with their zero entries, again by
+    ascending position (a zero value makes the index a don't-care).
     """
-    if w.shape[axis] % cfg.m != 0:
-        raise ValueError(
-            f"axis {axis} size {w.shape[axis]} not divisible by M={cfg.m}"
-        )
-    wl = _move_axis_last(w, axis)
-    lead = wl.shape[:-1]
-    blocks = wl.reshape(*lead, wl.shape[-1] // cfg.m, cfg.m)
-    nz = blocks != 0
-    # Order: non-zeros first (by position), then zeros. Stable sort on key:
-    # key = position + m * (is_zero) keeps ascending-position among non-zeros.
-    pos = jnp.arange(cfg.m, dtype=jnp.int32)
-    key = jnp.where(nz, pos, pos + cfg.m)
-    order = jnp.argsort(key, axis=-1, stable=True)  # (..., blocks, m)
-    take = order[..., : cfg.n]  # first n slots
-    values = jnp.take_along_axis(blocks, take, axis=-1)
-    idx = take.astype(jnp.int8)
-    values = values.reshape(*lead, -1)
-    idx = idx.reshape(*lead, -1)
-    return jnp.moveaxis(values, -1, axis), jnp.moveaxis(idx, -1, axis)
+    axis = _check_axis(w, cfg, axis)
+    slots = _block_slots(w, cfg.m, axis)
+    nz = [x != 0 for x in slots]
+    nnz = sum(z.astype(jnp.int32) for z in nz)
+    # slot of position j: non-zeros first by position, then zeros
+    slot_of = []
+    nz_before = jnp.zeros_like(nnz)
+    for j in range(cfg.m):
+        slot_of.append(jnp.where(nz[j], nz_before, nnz + j - nz_before))
+        nz_before = nz_before + nz[j].astype(jnp.int32)
+    values, idx = [], []
+    for s in range(cfg.n):
+        v = jnp.zeros_like(slots[0])
+        i = jnp.zeros(slots[0].shape, jnp.int8)
+        for j in range(cfg.m):
+            v = jnp.where(slot_of[j] == s, slots[j], v)
+            i = jnp.where(slot_of[j] == s, jnp.int8(j), i)
+        values.append(v)
+        idx.append(i)
+    return _interleave(values, axis), _interleave(idx, axis)
 
 
 def decompress_nm(

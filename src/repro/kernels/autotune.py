@@ -59,6 +59,8 @@ _LEGACY_VERSION = "v1"  # pre-backend-axis keys: always the tpu family
 
 _LOCK = threading.Lock()
 _MEM: dict[str, tuple] = {}
+# why each candidate of the most recent tune() call failed: block -> reason
+failures: dict[tuple, str] = {}
 _LOADED_FROM: Optional[str] = None
 
 
@@ -301,10 +303,12 @@ def tune(
             run = run_gpu_padded if gpu else run_pallas_padded
 
     best, best_t = None, float("inf")
+    failures.clear()
     for block in candidates or candidate_blocks(m, n, kk, cfg, family,
                                                 backend):
         plan = plan_nm_matmul(m, n, kk, cfg, block)
         if plan is None:
+            failures[tuple(block)] = "shape does not normalize to this block"
             continue
         try:
             run(
@@ -314,13 +318,17 @@ def tune(
                 _time_once(run, x, vals, idx, cfg, plan, interpret)
                 for _ in range(repeats)
             )
-        except Exception:  # noqa: BLE001 — infeasible on this backend
+        except Exception as e:  # noqa: BLE001 — e.g. Mosaic refuses it
+            failures[tuple(block)] = f"{type(e).__name__}: {e}"
             continue
         if t < best_t:
             best, best_t = plan.block, t
     if best is None:
-        best = plan_nm_matmul(m, n, kk, cfg,
-                              default_block(family, backend)).block
+        raise RuntimeError(
+            f"autotune: no candidate block ran for M={m} K={k} N={n} "
+            f"{cfg.tag} {jnp.dtype(dtype).name} family={family or 'default'}"
+            f" backend={backend}: "
+            + "; ".join(f"{b}: {why}" for b, why in failures.items()))
     with _LOCK:
         _load_locked()
         _MEM[_key(m, n, k, cfg, dtype, platform, backend, family)] = best

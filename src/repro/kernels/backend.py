@@ -12,9 +12,10 @@ Two kernel backends exist:
 
   tpu   the original family (:mod:`repro.kernels.indexmac` /
         ``indexmac_gather``): Mosaic lowering, VMEM scratch
-        accumulators, SMEM scalar index reads. Off-TPU it runs in the
-        Pallas interpreter (the historical CPU-test behavior), so it is
-        always *available*.
+        accumulators, SMEM scalar index reads. It runs compiled on a
+        TPU and in the Pallas interpreter on the CPU (the test suite);
+        on any other platform it is unavailable, so a chip host whose
+        JAX came up on something else fails instead of serving.
   gpu   the Pallas-on-Triton family (:mod:`repro.kernels.indexmac_gpu`):
         grid over output tiles only (every grid dim is a parallel
         program instance — there is no sequential-grid accumulator), the
@@ -87,7 +88,11 @@ def backend_unavailable_reason(backend: str) -> Optional[str]:
     human-readable reason (used both for the typed force error and for
     registry skip diagnostics)."""
     if backend == "tpu":
-        return None  # interpreter fallback keeps the family runnable
+        if jax.default_backend() in ("tpu", "cpu"):
+            return None
+        return ("the tpu kernel family runs only on a TPU (compiled) or "
+                "the CPU (interpreted); jax.default_backend()="
+                f"{jax.default_backend()!r}")
     if backend == "gpu":
         if jax.default_backend() == "gpu" or gpu_interpret_opt_in():
             return None
@@ -128,7 +133,16 @@ def resolve_backend(requested: Optional[str] = None, *,
 
 def interpret_for(backend: str) -> bool:
     """Whether a Pallas kernel of ``backend`` must run interpreted on
-    this host: the TPU family interprets off-TPU, the GPU family
-    interprets off-GPU (reachable only under the explicit
-    ``REPRO_GPU_INTERPRET=1`` opt-in)."""
-    return jax.default_backend() != backend
+    this host: the TPU family interprets only on the CPU and raises on
+    any platform other than TPU or CPU; the GPU family interprets off-GPU
+    (reachable only under the explicit ``REPRO_GPU_INTERPRET=1``
+    opt-in)."""
+    platform = jax.default_backend()
+    if backend == "tpu":
+        why = backend_unavailable_reason("tpu")
+        if why is not None:
+            from repro.kernels.registry import KernelForceError
+
+            raise KernelForceError(why)
+        return platform == "cpu"
+    return platform != backend

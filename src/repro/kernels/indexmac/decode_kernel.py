@@ -2,22 +2,15 @@
 
 The serving decode step issues M = slots (1-8) row GEMMs against every
 projection — shapes where the prefill kernel's (mi, ni, ki) tiling is
-all padding and XLA's dense reference wins by default. This module is
-the TPU mirror of the operand-reuse restructuring in the follow-up
-paper (arXiv 2501.10189, §IV): instead of expanding the compressed tile
-to a dense (bk, bn) weight block and paying a full-size MXU pass, the
-*activation* rows are the operand that gets "indexed":
+all padding and XLA's dense reference wins by default. This family drops
+the M tiling:
 
 * grid is (ni, ki) — no M tiling; the whole padded x block (8, bk)
   pins in VMEM across the entire sweep (the stationary operand).
-* ``vals``/``idx`` stream exactly once per (n, k) block.
-* for each in-block offset pair (s, j) the kernel contracts the strided
-  x column slice ``x[:, j::m]`` against the masked compressed rows
-  ``where(idx[s::n] == j, vals[s::n], 0)`` — an (8, bk/m) x (bk/m, bn)
-  dot. Summed over the n*m offset pairs this is exactly y = x @ W, with
-  m-fold less MXU work than the dense-expansion kernel and no (bk, bn)
-  intermediate; the bounded ``idx`` compare is the vindexmac analogue
-  (a local select, never an HBM gather).
+* ``vals``/``idx`` stream exactly once per (n, k) block, in their
+  logical layout, and expand in-register to a dense (bk, bn) tile with
+  the prefill kernel's repeat, roll and select (the vindexmac analogue: a local, bounded
+  select, never an HBM gather) before one (8, bk) x (bk, bn) dot.
 * the epilogue — dequant scales (int8 family), bias add, activation —
   runs on the f32 accumulator at writeback (see
   :mod:`repro.kernels.epilogue` for the composition contract), so a
@@ -39,22 +32,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro import compat
 from repro.core.sparsity import NMConfig
 from repro.kernels.epilogue import ACTIVATIONS
-
-
-def _decode_partial(x, v, ii, n: int, m: int):
-    """Sum of per-(s, j) offset dots: the (bm, bk) x block against a
-    compressed (bkc, bn) tile, contracted without densifying W."""
-    bm = x.shape[0]
-    bn = v.shape[1]
-    acc = jnp.zeros((bm, bn), dtype=jnp.float32)
-    for s in range(n):
-        v_s = v[s::n, :].astype(jnp.float32)  # (bk/m, bn)
-        i_s = ii[s::n, :].astype(jnp.int32)
-        for j in range(m):
-            xj = x[:, j::m]  # (bm, bk/m): dense rows j, j+m, ... of K
-            w_sj = jnp.where(i_s == j, v_s, 0.0)
-            acc += jax.lax.dot(xj, w_sj, preferred_element_type=jnp.float32)
-    return acc
+from repro.kernels.indexmac.kernel import check_pattern, decompress_block
 
 
 def _writeback(acc, o_ref, scales_ref, bias_ref, *, activation, out_dtype):
@@ -80,8 +58,9 @@ def _decode_kernel(x_ref, vals_ref, idx_ref, *rest, n, m, nk, out_dtype,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    w = decompress_block(vals_ref[...], idx_ref[...], n, m)  # (bk, bn) f32
     x = x_ref[...].astype(jnp.float32)
-    acc_ref[...] += _decode_partial(x, vals_ref[...], idx_ref[...], n, m)
+    acc_ref[...] += jax.lax.dot(x, w, preferred_element_type=jnp.float32)
 
     @pl.when(ki == nk - 1)
     def _done():
@@ -161,6 +140,7 @@ def _pallas_decode(x, vals, idx, scales, bias, *, cfg, block_n, block_k,
         raise ValueError(f"vals rows {kc} inconsistent with K={kk} and {cfg.tag}")
     if idx.shape != vals.shape:
         raise ValueError("idx/vals shape mismatch")
+    check_pattern(cfg)
     if mm % 8:
         raise ValueError(f"decode kernel needs M a sublane multiple, got {mm}")
     if activation is not None and activation not in ACTIVATIONS:

@@ -17,6 +17,8 @@ TPU adaptation of the paper's vindexmac + B-stationary dataflow
 * The bounded indices are expanded *inside VMEM* into a dense tile via
   iota-compare selects (the indirect-register-read analogue: a local,
   bounded indexed operation, never an HBM gather) and handed to the MXU.
+  The compressed pair is read in its logical layout, with no copy;
+  :func:`decompress_block` separates the slots with sublane rolls.
 
 Accumulation is fp32 in a VMEM scratch buffer, output written on the last
 k step.
@@ -34,24 +36,47 @@ from repro import compat
 from repro.core.sparsity import NMConfig
 
 
-def _decompress_block(v, ii, n: int, m: int):
-    """Expand a compressed (bkc, bn) block to dense (bk, bn), bk = bkc*m/n.
+# scoped VMEM for the prefill kernels: the dense tile's f32 temporaries
+# at the default (bk, bn) = (2048, 256) take ~17 MiB, over Mosaic's
+# 16 MiB default; 32 MiB fits in every TPU generation's VMEM
+VMEM_LIMIT = 32 * 2**20
 
-    Dense row d takes contributions from compressed rows (d//m)*n + s,
-    s in [0, n): w[d, c] = sum_s v[(d//m)*n+s, c] * (idx[...]==d%m).
-    Implemented with 2D-friendly ops (strided slice + repeat + iota select)
-    so it lowers cleanly in Mosaic.
+
+def check_pattern(cfg: NMConfig) -> None:
+    """The kernels' one limit on the N:M pattern (see
+    :func:`decompress_block`)."""
+    if cfg.m % cfg.n:
+        raise ValueError(f"{cfg.tag}: the kernels need n to divide m")
+
+
+def decompress_block(v, ii, n: int, m: int):
+    """Expand a compressed (bk*n/m, bn) block to dense (bk, bn) f32.
+
+    The block is in the logical layout: the n kept values of M-group g
+    sit in rows g*n .. g*n+n-1. Dense row d = g*m + j takes slot s where
+    ``idx == j``: w[d, c] = sum_s v[g*n+s, c] * (ii[g*n+s, c] == j).
+
+    Picking slot s of every group is a sublane-strided slice, which
+    Mosaic does not lower. Instead each row is repeated q = m/n times, so
+    slot s of group g lands on dense rows g*m + s*q .. g*m + s*q + q-1.
+    A value repeated onto row r with ``idx == r%m - k*q`` belongs on
+    row r - k*q, its own group's row for that idx (0 <= idx < m keeps it
+    in the group), and exactly one repeat of each kept value meets that
+    test for some k in (-n, n): one masked select and one sublane roll
+    per k move every kept value home. Repeat, roll and iota-compare
+    selects all lower. Needs n to divide m (the op layer routes other
+    patterns to the reference).
     """
     bkc, bn = v.shape
-    bk = bkc * m // n
-    jpos = jax.lax.broadcasted_iota(jnp.int32, (bk, bn), 0) % m
+    q = m // n
+    bk = bkc * q
+    j = jax.lax.broadcasted_iota(jnp.int32, (bk, bn), 0) % m
+    v_rep = jnp.repeat(v.astype(jnp.float32), q, axis=0)  # (bk, bn)
+    i_rep = jnp.repeat(ii.astype(jnp.int32), q, axis=0)
     w = jnp.zeros((bk, bn), dtype=jnp.float32)
-    for s in range(n):
-        v_s = v[s::n, :]  # (bkc/n, bn) = (bk/m, bn)
-        i_s = ii[s::n, :].astype(jnp.int32)
-        v_rep = jnp.repeat(v_s, m, axis=0)  # (bk, bn)
-        i_rep = jnp.repeat(i_s, m, axis=0)
-        w = w + jnp.where(i_rep == jpos, v_rep.astype(jnp.float32), 0.0)
+    for k in range(1 - n, n):  # slot order at every dense row
+        t = jnp.where(i_rep == j - k * q, v_rep, 0.0)
+        w = w + (t if k == 0 else pltpu.roll(t, (-k * q) % bk, 0))
     return w
 
 
@@ -62,7 +87,7 @@ def _nm_spmm_kernel(x_ref, vals_ref, idx_ref, o_ref, acc_ref, *, n, m, nk, out_d
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = _decompress_block(vals_ref[...], idx_ref[...], n, m)  # (bk, bn) f32
+    w = decompress_block(vals_ref[...], idx_ref[...], n, m)  # (bk, bn) f32
     x = x_ref[...].astype(jnp.float32)
     acc_ref[...] += jax.lax.dot(
         x, w, preferred_element_type=jnp.float32
@@ -88,10 +113,10 @@ def _nm_spmm_q_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # _decompress_block casts the int8 values to f32 in-register: exact
+    # decompress_block casts the int8 values to f32 in-register: exact
     # (|q| <= 127 << 2^24), so the MXU sees the integer lattice scaled
     # only at the end.
-    w = _decompress_block(vals_ref[...], idx_ref[...], n, m)  # (bk, bn) f32
+    w = decompress_block(vals_ref[...], idx_ref[...], n, m)  # (bk, bn) f32
     x = x_ref[...].astype(jnp.float32)
     acc_ref[...] += jax.lax.dot(
         x, w, preferred_element_type=jnp.float32
@@ -132,6 +157,7 @@ def nm_spmm_pallas_q(
         raise ValueError(f"vals rows {kc} inconsistent with K={kk} and {cfg.tag}")
     if idx.shape != vals.shape:
         raise ValueError("idx/vals shape mismatch")
+    check_pattern(cfg)
     if vals.dtype != jnp.int8:
         raise ValueError(f"quantized kernel needs int8 vals, got {vals.dtype}")
     if scales.shape != (nn,):
@@ -166,7 +192,8 @@ def nm_spmm_pallas_q(
         out_shape=jax.ShapeDtypeStruct((mm, nn), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT,
         ),
         interpret=interpret,
     )(x, vals, idx, scales.astype(jnp.float32).reshape(1, nn))
@@ -199,6 +226,7 @@ def nm_spmm_pallas(
         raise ValueError(f"vals rows {kc} inconsistent with K={kk} and {cfg.tag}")
     if idx.shape != vals.shape:
         raise ValueError("idx/vals shape mismatch")
+    check_pattern(cfg)
     block_k = min(block_k, kk)
     block_m = min(block_m, mm)
     block_n = min(block_n, nn)
@@ -228,7 +256,8 @@ def nm_spmm_pallas(
         out_shape=jax.ShapeDtypeStruct((mm, nn), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT,
         ),
         interpret=interpret,
     )(x, vals, idx)

@@ -173,6 +173,17 @@ def _decode_supports(ctx: dict) -> Optional[str]:
     return None
 
 
+def _tpu(supports):
+    """``supports`` plus the TPU kernels' one limit on the N:M pattern
+    (see :func:`repro.kernels.indexmac.kernel.decompress_block`)."""
+    def check(ctx: dict) -> Optional[str]:
+        nm = ctx["cfg"]
+        if ctx["use_kernel"] and nm.m % nm.n:
+            return f"{nm.tag}: the TPU kernels need n to divide m"
+        return supports(ctx)
+    return check
+
+
 # ---------------------------------------------------------------------------
 # prefill-shaped family (M > decode_m_max): (mi, ni, ki)-tiled kernel
 # ---------------------------------------------------------------------------
@@ -198,7 +209,7 @@ def run_pallas_padded(
 
 
 @registry.register("nm_matmul", "pallas_padded", priority=100,
-                   supports=_pallas_supports, uses_plan=True,
+                   supports=_tpu(_pallas_supports), uses_plan=True,
                    backend="tpu")
 def _run_pallas_impl(x2, vals, idx, *, cfg, plan, interpret):
     return run_pallas_padded(
@@ -237,7 +248,7 @@ def run_pallas_padded_q(
 
 
 @registry.register("nm_matmul_q", "pallas_padded_q", priority=100,
-                   supports=_pallas_supports, uses_plan=True,
+                   supports=_tpu(_pallas_supports), uses_plan=True,
                    backend="tpu")
 def _run_pallas_q_impl(x2, vals, idx, scales, *, cfg, plan, interpret):
     return run_pallas_padded_q(
@@ -282,7 +293,7 @@ def run_pallas_decode(
 
 
 @registry.register("nm_matmul_decode", "pallas_decode", priority=100,
-                   supports=_decode_supports, uses_plan=True,
+                   supports=_tpu(_decode_supports), uses_plan=True,
                    backend="tpu")
 def _run_pallas_decode_impl(x2, vals, idx, bias, *, cfg, plan, activation,
                             interpret):
@@ -331,7 +342,7 @@ def run_pallas_decode_q(
 
 
 @registry.register("nm_matmul_decode_q", "pallas_decode_q", priority=100,
-                   supports=_decode_supports, uses_plan=True,
+                   supports=_tpu(_decode_supports), uses_plan=True,
                    backend="tpu")
 def _run_pallas_decode_q_impl(x2, vals, idx, scales, bias, *, cfg, plan,
                               activation, interpret):
@@ -630,7 +641,9 @@ def _nm_matmul_fwd_impl(x, vals, idx, cfg, use_kernel, block, force):
     )
     y2 = registry.dispatch(
         "nm_matmul", ctx, x2, vals, idx,
-        cfg=cfg, plan=plan, interpret=interpret_for("tpu"),
+        cfg=cfg, plan=plan,
+        # only a Pallas impl reads it: the reference route runs anywhere
+        interpret=use_kernel and interpret_for("tpu"),
     )
     return y2.reshape(*lead, nn)
 
@@ -689,7 +702,9 @@ def nm_matmul_q_positional(
     )
     y2 = registry.dispatch(
         "nm_matmul_q", ctx, x2, vals, idx, scales,
-        cfg=cfg, plan=plan, interpret=interpret_for("tpu"),
+        cfg=cfg, plan=plan,
+        # only a Pallas impl reads it: the reference route runs anywhere
+        interpret=use_kernel and interpret_for("tpu"),
     )
     return y2.reshape(*lead, nn)
 

@@ -57,6 +57,7 @@ class DispatchRecord:
     block: Optional[tuple]  # (block_m, block_n, block_k) when applicable
     reason: str  # why higher-priority impls were skipped ("" if none)
     backend: str = "tpu"  # resolved kernel backend the call routed under
+    interpret: bool = False  # True when a Pallas impl ran in the interpreter
 
 
 class KernelForceError(RuntimeError):
@@ -179,6 +180,8 @@ def dispatch(op: str, ctx: dict, *args, **kwargs):
                 block=plan.block if uses_plan else None,
                 reason="; ".join(skipped),
                 backend=backend,
+                interpret=(impl.backend != "any"
+                           and bool(kwargs.get("interpret"))),
             )
         )
         return out

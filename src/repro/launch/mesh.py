@@ -6,18 +6,18 @@ devices to be configured before first jax init.
 """
 from __future__ import annotations
 
-import jax
+from repro import compat
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return compat.make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Degenerate 1x1 mesh on the local device — smoke tests / examples."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return compat.make_mesh((1, 1), ("data", "model"))
 
 
 def make_serve_mesh(data: int = 1, model: int = 1):
@@ -25,6 +25,4 @@ def make_serve_mesh(data: int = 1, model: int = 1):
     "data", tensor parallelism over "model". Works against real devices
     or a forced host platform (XLA_FLAGS=--xla_force_host_platform_
     device_count=N set before jax initializes)."""
-    from repro import compat
-
     return compat.make_mesh((data, model), ("data", "model"))

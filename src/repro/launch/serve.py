@@ -10,6 +10,13 @@ Multi-device (tensor-parallel x data-parallel) serving:
 (on real accelerators drop the XLA_FLAGS override — the mesh axes map
 onto the attached devices; slots must divide the data axis).
 
+Published widths: ``--full`` builds the arch's published config
+(``get_config``) with bf16 parameters created on the device, and
+``--layers N`` cuts it to its first N layers, every width unchanged:
+
+  PYTHONPATH=src python -m repro.launch.serve --full --layers 8 --kernels \
+      --slots 8 --prefill-len 128 --max-new 32 --max-seq 160
+
 Observability: ``--trace-out trace.json`` / ``--metrics-out
 metrics.prom`` enable the :mod:`repro.obs` layer for the run (same as
 ``REPRO_OBS=1``) and export a Perfetto-loadable Chrome trace and a
@@ -25,17 +32,71 @@ import dataclasses
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 import repro.obs as obs_mod
-from repro.configs import ARCHS, get_reduced
+from repro.configs import ARCHS, cut_layers, get_config, get_reduced
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.transformer import LM
 from repro.serving.engine import Request, ServeEngine, ShardedServeEngine
+
+
+def device_info() -> dict:
+    """The device JAX serves on, as every result line names it."""
+    d = jax.devices()[0]
+    return {"platform": d.platform, "kind": d.device_kind,
+            "count": len(jax.devices())}
+
+
+def build_lm(arch: str, *, full: bool = False, layers=None,
+             kernels: bool = False, seed: int = 0, mesh=None):
+    """``(lm, params)`` as the serve driver builds them.
+
+    ``full`` takes the published config with bf16 parameters created on
+    the device (``LM.init`` builds them inside jit, a group at a time);
+    otherwise the reduced CPU-runnable config with f32 params.
+    ``layers`` cuts the decoder to its first N layers; ``kernels``
+    routes the compressed GEMMs through the Pallas kernel families.
+    With a ``("data", "model")`` ``mesh`` every param is created in the
+    shard :class:`ShardedServeEngine` keeps it in, so no device holds
+    the whole model on the way; the values are the same as without.
+    """
+    cfg = get_config(arch) if full else get_reduced(arch)
+    if layers is not None:
+        cfg = cut_layers(cfg, layers)
+    if kernels:
+        if cfg.sparsity is None:
+            raise SystemExit(
+                f"--kernels: {arch} has a dense config (no compressed "
+                "GEMMs to route)")
+        cfg = dataclasses.replace(
+            cfg, sparsity=dataclasses.replace(cfg.sparsity, use_kernel=True))
+    lm = LM(cfg)
+    key = jax.random.PRNGKey(seed)
+    dtype = jnp.bfloat16 if full else jnp.float32
+    shardings = None
+    if mesh is not None:
+        from repro.parallel.sharding import (
+            serve_param_shardings,
+            serve_tp_plan,
+        )
+
+        plan = serve_tp_plan(cfg, dict(zip(mesh.axis_names,
+                                           mesh.devices.shape))["model"])
+        shardings = serve_param_shardings(
+            jax.eval_shape(lambda k: lm.init(k, dtype), key), mesh, plan)
+    return lm, lm.init(key, dtype, shardings=shardings)
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ARCHS), default="yi-9b")
+    ap.add_argument("--full", action="store_true",
+                    help="serve the published config (bf16 params) "
+                         "instead of the reduced one")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the decoder to its first N layers")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--prefill-len", type=int, default=16)
@@ -75,21 +136,23 @@ def main() -> None:
                          "text snapshot here on exit")
     args = ap.parse_args()
 
+    use_compile_cache()
+    print("device", device_info(), flush=True)
     bundle = None
     if args.trace_out or args.metrics_out:
         bundle = obs_mod.enable()
 
-    cfg = get_reduced(args.arch)
-    if args.kernels:
-        if cfg.sparsity is None:
-            raise SystemExit(
-                f"--kernels: {args.arch} reduces to a dense config "
-                "(no compressed GEMMs to route)")
-        cfg = dataclasses.replace(
-            cfg, sparsity=dataclasses.replace(
-                cfg.sparsity, use_kernel=True))
-    lm = LM(cfg)
-    params = lm.init(jax.random.PRNGKey(0))
+    mesh = None
+    if args.mesh:
+        from repro.launch.mesh import make_serve_mesh
+
+        data, model = (int(x) for x in args.mesh.lower().split("x"))
+        mesh = make_serve_mesh(data, model)
+    lm, params = build_lm(args.arch, full=args.full, layers=args.layers,
+                          kernels=args.kernels, mesh=mesh)
+    cfg = lm.cfg
+    print(f"model {cfg.name}: {cfg.n_layers} layers, d_model "
+          f"{cfg.d_model}, vocab {cfg.vocab_size}", flush=True)
     kw = dict(slots=args.slots, max_seq=args.max_seq,
               prefill_len=args.prefill_len,
               prefill_chunk=args.prefill_chunk,
@@ -97,13 +160,9 @@ def main() -> None:
               quantize=args.quantize, strict=args.strict,
               paged=args.paged, page_size=args.page_size,
               pool_pages=args.pool_pages)
-    if args.mesh:
-        from repro.launch.mesh import make_serve_mesh
-
-        data, model = (int(x) for x in args.mesh.lower().split("x"))
-        mesh = make_serve_mesh(data, model)
+    if mesh is not None:
         eng = ShardedServeEngine(lm, params, mesh=mesh, **kw)
-        print(f"mesh data={data} model={model}: {eng.tp_plan}")
+        print(f"mesh {args.mesh}: {eng.tp_plan}")
     else:
         eng = ServeEngine(lm, params, **kw)
     rng = np.random.default_rng(0)
@@ -126,8 +185,8 @@ def main() -> None:
     for r in done[:3]:
         print(f"  rid={r.rid} out[:8]={r.out[:8]}")
     assert len(done) == args.requests
-    assert eng.compiled_cache_sizes() in \
-        ({"prefill": 1, "decode": 1}, {"prefill": -1, "decode": -1}), \
+    # exactly one compile per step: -1 (size unavailable) is a failure
+    assert eng.compiled_cache_sizes() == {"prefill": 1, "decode": 1}, \
         eng.compiled_cache_sizes()
     if bundle is not None:
         if args.trace_out:

@@ -3,6 +3,10 @@
 Example (the (b) deliverable end-to-end run):
   PYTHONPATH=src python -m repro.launch.train --arch yi-9b --reduced \
       --steps 300 --batch 8 --seq 64 --ckpt-dir /tmp/ckpt
+
+``--no-reduced`` takes the published config with bf16 params built by
+``LM.init`` (inside jit, a group at a time); ``--layers N`` cuts it to
+its first N layers.
 """
 from __future__ import annotations
 
@@ -13,8 +17,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import ARCHS, get_config, get_reduced
+from repro.configs import ARCHS, cut_layers, get_config, get_reduced
 from repro.data.pipeline import DataPipeline, PipelineConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.transformer import LM
 from repro.optim.optimizer import AdamWConfig
 from repro.training.checkpoint import Checkpointer
@@ -25,7 +30,12 @@ from repro.optim.optimizer import adamw_init
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ARCHS), default="yi-9b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced CPU-runnable config (--no-reduced: the "
+                         "published one, bf16 params)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the decoder to its first N layers")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
@@ -37,16 +47,18 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = (get_reduced if args.reduced else get_config)(
         args.arch, sparse=not args.dense)
+    if args.layers is not None:
+        cfg = cut_layers(cfg, args.layers)
     lm = LM(cfg)
     tcfg = TrainConfig(
         opt=AdamWConfig(lr=args.lr, warmup_steps=20, total_steps=args.steps),
         microbatches=args.microbatches, remat=args.remat)
     step_fn = jax.jit(make_train_step(lm, tcfg))
 
-    params = lm.init(jax.random.PRNGKey(0))
     opt_state = adamw_init(params)
     pipe = DataPipeline(PipelineConfig(
         vocab_size=cfg.vocab_size, seq_len=args.seq,

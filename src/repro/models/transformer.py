@@ -210,11 +210,28 @@ def _super_init(key, blocks: tuple[Block, ...], cfg: ModelConfig, param_dtype):
 
 
 def group_init(key, entry, repeat: int, cfg: ModelConfig, param_dtype):
+    """Stacked params of one group. ``lax.map`` builds one period at a
+    time, so a layer's dense f32 draw (before pruning and compression)
+    is the largest temporary — never the dense stack of the group."""
     blocks = _as_blocks(entry)
     if repeat == 1:
         return _super_init(key, blocks, cfg, param_dtype)
     keys = jax.random.split(key, repeat)
-    return jax.vmap(lambda k: _super_init(k, blocks, cfg, param_dtype))(keys)
+    return jax.lax.map(lambda k: _super_init(k, blocks, cfg, param_dtype),
+                       keys)
+
+
+def _head_init(key, d: int, vocab: int, param_dtype):
+    return linear_init(key, d, vocab, sp=None, param_dtype=param_dtype)
+
+
+def _init_on(fn, static_argnums, shardings=None):
+    """``fn`` as an init piece: inside jit, so its output is created
+    where it will live and in param_dtype — with ``shardings`` already
+    sharded, else on the default device. Compiles once per (config,
+    dtype, shardings)."""
+    kw = {} if shardings is None else {"out_shardings": shardings}
+    return jax.jit(fn, static_argnums=static_argnums, **kw)
 
 
 def group_empty_cache(entry, repeat: int, batch: int, max_seq: int,
@@ -272,29 +289,44 @@ class LM:
     cfg: ModelConfig
 
     # ---- init -----------------------------------------------------------
-    def init(self, key: jax.Array, param_dtype=jnp.float32) -> dict:
+    def init(self, key: jax.Array, param_dtype=jnp.float32,
+             shardings: Any = None) -> dict:
+        """Random params in ``param_dtype``, built inside jit a group at
+        a time (see :func:`group_init`), so a published-width model
+        initializes directly on the device it is served from.
+
+        ``shardings`` — a tree of ``Sharding`` matching the params (e.g.
+        mapped over ``jax.eval_shape`` of this method) — creates every
+        piece already sharded, so no device ever holds more than its
+        share. The values do not depend on it."""
         cfg = self.cfg
+        sh = shardings or {}
+        group_sh = sh.get("groups") or [None] * len(cfg.plan)
         ks = jax.random.split(key, 5 + len(cfg.plan)
                               + len(cfg.encoder_plan or ()))
         p: dict[str, Any] = {
-            "embed": embedding_init(ks[0], cfg.vocab_size, cfg.d_model,
-                                    param_dtype),
+            "embed": _init_on(embedding_init, (1, 2, 3), sh.get("embed"))(
+                ks[0], cfg.vocab_size, cfg.d_model, param_dtype),
             "final_norm": rmsnorm_init(cfg.d_model, param_dtype),
         }
         if cfg.pos_embed == "learned":
             p["pos"] = (jax.random.normal(ks[1], (cfg.max_seq, cfg.d_model))
                         * 0.02).astype(param_dtype)
         if not cfg.tie_embeddings:
-            p["lm_head"] = linear_init(ks[2], cfg.d_model, cfg.vocab_size,
-                                       sp=None, param_dtype=param_dtype)
+            p["lm_head"] = _init_on(_head_init, (1, 2, 3),
+                                    sh.get("lm_head"))(
+                ks[2], cfg.d_model, cfg.vocab_size, param_dtype)
         p["groups"] = [
-            group_init(ks[5 + i], blk, rep, cfg, param_dtype)
+            _init_on(group_init, (1, 2, 3, 4), group_sh[i])(
+                ks[5 + i], blk, rep, cfg, param_dtype)
             for i, (blk, rep) in enumerate(cfg.plan)
         ]
         if cfg.encoder_plan is not None:
             off = 5 + len(cfg.plan)
+            enc_sh = sh.get("enc_groups") or [None] * len(cfg.encoder_plan)
             p["enc_groups"] = [
-                group_init(ks[off + i], blk, rep, cfg, param_dtype)
+                _init_on(group_init, (1, 2, 3, 4), enc_sh[i])(
+                    ks[off + i], blk, rep, cfg, param_dtype)
                 for i, (blk, rep) in enumerate(cfg.encoder_plan)
             ]
             p["enc_final_norm"] = rmsnorm_init(cfg.d_model, param_dtype)
@@ -304,6 +336,8 @@ class LM:
             if cfg.encoder_inputs == "tokens":
                 p["enc_embed"] = embedding_init(ks[4], cfg.vocab_size,
                                                 cfg.d_model, param_dtype)
+        if shardings is not None:
+            p = jax.device_put(p, shardings)  # the small pieces built eagerly
         return p
 
     # ---- caches ----------------------------------------------------------

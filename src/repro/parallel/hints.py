@@ -3,8 +3,9 @@
 Model code calls `shard_hint(x, "model", None, ...)` to pin intermediate
 layouts (expert buffers, attention activations). Under pjit with an active
 mesh the hint becomes a with_sharding_constraint; in single-device smoke
-tests it vanishes. Mesh-context discovery goes through `repro.compat` so
-the same code runs on jax 0.4.x and 0.5.x.
+tests it vanishes. Mesh-context discovery goes through `repro.compat`,
+whose meshes have Auto axes, so the constraint is a hint to the
+partitioner and never an assertion.
 
 `tp_reduce` / `tp_serving` are the manual-collective counterpart for
 shard_map regions: the sharded serving engine traces the model inside a

@@ -426,6 +426,16 @@ def serve_param_pspecs(params: Any, mesh: Mesh, plan: ServeTPPlan):
     )
 
 
+def serve_param_shardings(params: Any, mesh: Mesh, plan: ServeTPPlan):
+    """:func:`serve_param_pspecs` as ``NamedSharding``s: where the
+    sharded serve engine keeps each param (``device_put``, or
+    ``LM.init(shardings=...)`` to create them there). ``params`` may be
+    abstract (``jax.eval_shape``)."""
+    return jax.tree.map(lambda s: NamedSharding(mesh, s),
+                        serve_param_pspecs(params, mesh, plan),
+                        is_leaf=lambda x: isinstance(x, P))
+
+
 def serve_cache_pspecs(caches: Any, mesh: Mesh, plan: ServeTPPlan,
                        batch_axes=("data",)):
     """Decode-cache specs for TP serving: batch slots over "data", GQA

@@ -244,7 +244,7 @@ class ServeEngine:
             self._decode = jax.jit(decode_step, donate_argnums=(2,))
             return
 
-        def prefill_step(params, tokens, caches, cache_len, mask, key):
+        def prefill_logits(params, tokens, caches, cache_len, mask):
             if full:
                 logits, new_caches, _ = lm.forward(
                     params, tokens, view=CacheView.prefill(), caches=caches)
@@ -253,7 +253,12 @@ class ServeEngine:
                     params, tokens, view=CacheView.chunk(cache_len),
                     caches=caches)
             new_caches = merge_cache_slots(new_caches, caches, mask)
-            toks, key = sampler(logits[:, -1], key)
+            return logits[:, -1], new_caches
+
+        def prefill_step(params, tokens, caches, cache_len, mask, key):
+            logits, new_caches = prefill_logits(params, tokens, caches,
+                                                cache_len, mask)
+            toks, key = sampler(logits, key)
             return toks, new_caches, key
 
         def decode_step(params, token, caches, cache_len, mask, key):
@@ -266,8 +271,27 @@ class ServeEngine:
 
         self._prefill = jax.jit(prefill_step, donate_argnums=(2,))
         self._decode = jax.jit(decode_step, donate_argnums=(2,))
+        self._prefill_logits = jax.jit(prefill_logits)
 
     # ---- public API -------------------------------------------------------
+
+    def prefill_logits(self, tokens) -> jax.Array:
+        """Last-position logits of one prefill of ``tokens`` (slots,
+        prefill_len) on fresh caches: what the prefill step samples from,
+        through the engine's own model path (parity checks between
+        kernel and reference paths, or sharded and single-device
+        engines). The engine's caches and queue are untouched; this is a
+        separate compiled program, so it does not count in
+        :meth:`compiled_cache_sizes`."""
+        if self.paged:
+            raise NotImplementedError("prefill_logits: slot engines only")
+        caches = self._place_caches(
+            self.lm.init_cache(self.slots, self.max_seq))
+        logits, _ = self._prefill_logits(
+            self.params, jnp.asarray(tokens, jnp.int32), caches,
+            jnp.zeros((self.slots,), jnp.int32),
+            jnp.ones((self.slots,), bool))
+        return logits
 
     def submit(self, req: Request) -> None:
         self.scheduler.submit(req, now=time.perf_counter())
@@ -534,16 +558,12 @@ class ShardedServeEngine(ServeEngine):
         return self._mesh_shape["data"]
 
     def _place_params(self, params: Any) -> Any:
-        from jax.sharding import NamedSharding
-        from jax.sharding import PartitionSpec as P
+        from repro.parallel.sharding import serve_param_shardings
 
-        from repro.parallel.sharding import serve_param_pspecs
-
-        specs = serve_param_pspecs(params, self.mesh, self.tp_plan)
-        shardings = jax.tree.map(
-            lambda s: NamedSharding(self.mesh, s), specs,
-            is_leaf=lambda x: isinstance(x, P))
-        return jax.device_put(params, shardings)
+        # params built with LM.init(shardings=serve_param_shardings(...))
+        # are in place already: nothing moves
+        return jax.device_put(params, serve_param_shardings(
+            params, self.mesh, self.tp_plan))
 
     def _place_caches(self, caches: Any) -> Any:
         from jax.sharding import NamedSharding
@@ -683,3 +703,4 @@ class ShardedServeEngine(ServeEngine):
 
         self._prefill = jax.jit(prefill_step, donate_argnums=(2,))
         self._decode = jax.jit(decode_step, donate_argnums=(2,))
+        self._prefill_logits = jax.jit(sh_prefill)

@@ -217,6 +217,35 @@ def test_interpret_for_tracks_platform(monkeypatch):
     assert interpret_for("gpu") == (jax.default_backend() != "gpu")
 
 
+def test_positional_reference_route_runs_on_a_gpu_host(monkeypatch):
+    """The positional surfaces run the TPU family only: on a GPU host
+    their reference route (use_kernel=False) still computes, and only
+    the kernel route raises the typed error."""
+    from repro.kernels.indexmac.ops import (
+        nm_matmul_positional,
+        nm_matmul_q_positional,
+    )
+
+    cfg = NMConfig(2, 4)
+    kw, kx = jax.random.split(jax.random.PRNGKey(3))
+    sw = api.sparsify(jax.random.normal(kw, (32, 16)), cfg)
+    qw = api.quantize(sw)
+    x = jax.random.normal(kx, (4, 32))
+    want = nm_matmul_positional(x, sw.vals, sw.idx, cfg, use_kernel=False)
+    want_q = nm_matmul_q_positional(x, qw.vals, qw.idx, qw.scales, cfg,
+                                    use_kernel=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    monkeypatch.delenv("REPRO_GPU_INTERPRET", raising=False)
+    np.testing.assert_array_equal(
+        nm_matmul_positional(x, sw.vals, sw.idx, cfg, use_kernel=False),
+        want)
+    np.testing.assert_array_equal(
+        nm_matmul_q_positional(x, qw.vals, qw.idx, qw.scales, cfg,
+                               use_kernel=False), want_q)
+    with pytest.raises(api.KernelForceError, match="tpu"):
+        nm_matmul_positional(x, sw.vals, sw.idx, cfg, use_kernel=True)
+
+
 # ---------------------------------------------------------------------------
 # dispatch routing end-to-end under the interpreter opt-in
 # ---------------------------------------------------------------------------

@@ -13,7 +13,8 @@ from repro.kernels.indexmac.kernel import nm_spmm_pallas
 from repro.kernels.indexmac.ops import nm_matmul_positional as nm_matmul
 from repro.kernels.indexmac.ref import nm_matmul_ref
 
-CFGS = [NMConfig(1, 2), NMConfig(1, 4), NMConfig(2, 4)]
+CFGS = [NMConfig(1, 2), NMConfig(1, 4), NMConfig(2, 4), NMConfig(2, 8),
+        NMConfig(4, 8)]
 
 
 def _mk(cfg, K, N, M, dtype, seed=0):
@@ -93,6 +94,22 @@ def test_op_falls_back_on_odd_shapes():
     x, w, vals, idx = _mk(cfg, 36, 20, 5, jnp.float32)
     y = nm_matmul(x, vals, idx, cfg)
     np.testing.assert_allclose(np.asarray(y), np.asarray(x @ w), rtol=1e-4, atol=1e-3)
+
+
+def test_op_routes_patterns_the_kernel_cannot_expand_to_ref():
+    """n must divide m for the kernels' slot expansion: 3:8 runs on the
+    reference with the reason recorded, and the kernel itself refuses."""
+    from repro.kernels import registry
+
+    cfg = NMConfig(3, 8)
+    x, w, vals, idx = _mk(cfg, 256, 128, 64, jnp.float32)
+    y = nm_matmul(x, vals, idx, cfg)
+    rec = registry.last_dispatch("nm_matmul")
+    assert rec.impl == "reference" and "n to divide m" in rec.reason, rec
+    np.testing.assert_allclose(np.asarray(y), np.asarray(x @ w), rtol=1e-4,
+                               atol=1e-3)
+    with pytest.raises(ValueError, match="n to divide m"):
+        nm_spmm_pallas(x, vals, idx, cfg=cfg, interpret=True)
 
 
 def test_kernel_rejects_bad_shapes():

@@ -297,6 +297,28 @@ def test_decode_step_dispatches_zero_reference_paths(yi):
     assert not reference, reference
 
 
+def test_prefill_logits_match_full_forward_and_leave_state(yi):
+    """``prefill_logits`` is the prefill step's own model path on fresh
+    caches: the full forward's last-position logits, with the engine's
+    caches and queue untouched."""
+    from repro.serving.engine import make_serve_steps
+
+    cfg, lm, params = yi
+    eng = ServeEngine(lm, params, slots=2, max_seq=64, prefill_len=8)
+    toks = np.random.default_rng(5).integers(
+        0, cfg.vocab_size, size=(2, 8)).astype(np.int32)
+    before = jax.tree.map(np.asarray, eng.caches)
+    got = eng.prefill_logits(toks)
+    prefill, _ = make_serve_steps(lm)
+    want, _ = prefill(params, jnp.asarray(toks), lm.init_cache(2, 64))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    jax.tree.map(np.testing.assert_array_equal, before,
+                 jax.tree.map(np.asarray, eng.caches))
+    assert not eng.queue and eng.compiled_cache_sizes() == {
+        "prefill": 0, "decode": 0}
+
+
 def test_autotune_warmup_uses_each_weights_own_ratio(yi, monkeypatch):
     """A model mixing N:M ratios per target (2:4 ffn, 1:4 attn) must tune
     each compressed GEMM at the K its own NMConfig implies — the old

@@ -157,6 +157,26 @@ evs = bundle.tracer.events()
 assert any(e["ph"] == "b" for e in evs), "no request spans traced"
 assert any(e["name"] == "engine.decode" for e in evs), "no decode spans"
 print("OBSVARIANT ok")
+# params created already sharded (build_lm(mesh=...), as `serve --mesh`
+# builds them): the single-device init's values, in the shardings the
+# sharded engine keeps, and the same tokens
+from repro.launch.serve import build_lm
+from repro.parallel.sharding import serve_param_shardings, serve_tp_plan
+lm_s, p_sh = build_lm("yi-9b", mesh=mesh)
+_, p_one = build_lm("yi-9b")
+want = serve_param_shardings(p_one, mesh, serve_tp_plan(lm_s.cfg, 4))
+for a, b, sh in zip(jax.tree.leaves(p_sh), jax.tree.leaves(p_one),
+                    jax.tree.leaves(want)):
+    assert a.sharding.is_equivalent_to(sh, a.ndim), (a.sharding, sh)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+kw = dict(slots=2, max_seq=64, prefill_len=8)
+def serve_plain(eng):
+    for i, p in enumerate(pp):
+        eng.submit(Request(rid=i, prompt=p, max_new=4))
+    return {r.rid: tuple(r.out) for r in eng.run()}
+assert serve_plain(ShardedServeEngine(lm_s, p_sh, mesh=mesh, **kw)) == \
+    serve_plain(ServeEngine(lm_s, p_one, **kw))
+print("SHARDEDINIT ok")
 print("RESULT ok")
 """
 
@@ -201,6 +221,13 @@ def test_obs_on_sharded_parity_and_zero_recompiles(subproc):
     each, and actually record request spans + metrics (asserted
     in-subprocess)."""
     assert "OBSVARIANT ok" in subproc
+
+
+def test_sharded_init_matches_single_device(subproc):
+    """``build_lm(mesh=...)`` creates every param in the shard the
+    sharded engine keeps it in, with the single-device init's values
+    and the same served tokens (asserted in-subprocess)."""
+    assert "SHARDEDINIT ok" in subproc
 
 
 def test_kv_sharded_variant_actually_sharded_kv(subproc):

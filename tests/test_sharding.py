@@ -24,6 +24,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax
+from repro import compat
 from repro.configs import get_reduced
 from repro.configs.base import ShapeConfig
 from repro.launch.specs import make_cell, lower_cell
@@ -32,7 +33,7 @@ from repro.roofline.analysis import collective_bytes_from_hlo, analyze
 out = {}
 for mesh_shape, axes in [((4, 2), ("data", "model")),
                          ((2, 2, 2), ("pod", "data", "model"))]:
-    mesh = jax.make_mesh(mesh_shape, axes)
+    mesh = compat.make_mesh(mesh_shape, axes)
     for arch, sname, kind in [("yi-9b", "t", "train"),
                               ("deepseek-v2-lite-16b", "d", "decode"),
                               ("jamba-v0.1-52b", "p", "prefill")]:

@@ -176,3 +176,42 @@ def test_loss_decreases_end_to_end():
         params, opt, metrics = step(params, opt, batch)
         losses.append(float(metrics["loss"]))
     assert np.mean(losses[-10:]) < np.mean(losses[:10]) - 0.2, losses[::10]
+
+
+def _f32_shapes(jaxpr, out=None):
+    """Every f32 value shape in ``jaxpr`` and the jaxprs nested in it."""
+    out = set() if out is None else out
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            aval = getattr(v, "aval", None)
+            if getattr(aval, "dtype", None) == jnp.float32:
+                out.add(tuple(aval.shape))
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (list, tuple)) else (p,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _f32_shapes(inner, out)
+    return out
+
+
+def test_train_full_width_init_has_no_f32_group_stack():
+    """``train --no-reduced`` builds yi-9b's published widths in bf16
+    through LM.init, whose dense f32 draws exist one layer at a time."""
+    from repro.configs import cut_layers, get_config
+    from repro.core.nmweight import NMWeight
+
+    lm = LM(cut_layers(get_config("yi-9b", sparse=True), 2))
+
+    def init():
+        return lm.init(jax.random.PRNGKey(0), param_dtype=jnp.bfloat16)
+
+    params = jax.eval_shape(init)
+    w_up = params["groups"][0][0]["mlp"]["w_up"]
+    assert isinstance(w_up, NMWeight)
+    assert w_up.vals.shape == (2, 2048, 11008)
+    assert w_up.vals.dtype == jnp.bfloat16
+    assert params["embed"]["embedding"].dtype == jnp.bfloat16
+    shapes = _f32_shapes(jax.make_jaxpr(init)().jaxpr)
+    assert (4096, 11008) in shapes  # one layer's dense draw ...
+    assert not any(len(s) == 3 and s[0] == 2 and 11008 in s
+                   for s in shapes)  # ... never the group's stack
