@@ -18,6 +18,10 @@ the M tiling:
 
 Accumulation is f32 in VMEM scratch; on the integer lattice the result
 is bit-exact against the reference composition regardless of tiling.
+
+A call with a ``name`` (``<target>_<projection>``) shows in a profile as
+``nm_spmm_pallas_decode_<name>.N``; ``nm_spmm_pallas_decode`` stays the
+prefix of every decode kernel's device op, named or not.
 """
 from __future__ import annotations
 
@@ -32,7 +36,11 @@ from jax.experimental.pallas import tpu as pltpu
 from repro import compat
 from repro.core.sparsity import NMConfig
 from repro.kernels.epilogue import ACTIVATIONS
-from repro.kernels.indexmac.kernel import check_pattern, decompress_block
+from repro.kernels.indexmac.kernel import (
+    check_pattern,
+    decompress_block,
+    named_kernel,
+)
 
 
 def _writeback(acc, o_ref, scales_ref, bias_ref, *, activation, out_dtype):
@@ -71,7 +79,7 @@ def _decode_kernel(x_ref, vals_ref, idx_ref, *rest, n, m, nk, out_dtype,
 @functools.partial(
     jax.jit,
     static_argnames=("cfg", "block_n", "block_k", "activation", "out_dtype",
-                     "interpret"),
+                     "interpret", "name"),
 )
 def nm_spmm_pallas_decode(
     x: jax.Array,
@@ -85,6 +93,7 @@ def nm_spmm_pallas_decode(
     activation: Optional[str] = None,
     out_dtype=None,
     interpret: bool = False,
+    name: Optional[str] = None,
 ) -> jax.Array:
     """y = epilogue(x @ decompress(vals, idx)) for skinny x.
 
@@ -92,16 +101,17 @@ def nm_spmm_pallas_decode(
     pads 1..8 rows up to 8), N % block_n == 0, K % block_k == 0,
     block_k % m == 0; ``bias`` is (N,) when given.
     """
-    return _pallas_decode(x, vals, idx, None, bias, cfg=cfg,
-                          block_n=block_n, block_k=block_k,
-                          activation=activation, out_dtype=out_dtype,
-                          interpret=interpret)
+    run = functools.partial(_pallas_decode, cfg=cfg, block_n=block_n,
+                            block_k=block_k, activation=activation,
+                            out_dtype=out_dtype, interpret=interpret)
+    return named_kernel(run, "nm_spmm_pallas_decode", name)(
+        x, vals, idx, None, bias)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("cfg", "block_n", "block_k", "activation", "out_dtype",
-                     "interpret"),
+                     "interpret", "name"),
 )
 def nm_spmm_pallas_decode_q(
     x: jax.Array,
@@ -116,6 +126,7 @@ def nm_spmm_pallas_decode_q(
     activation: Optional[str] = None,
     out_dtype=None,
     interpret: bool = False,
+    name: Optional[str] = None,
 ) -> jax.Array:
     """int8 decode sibling: one byte per kept value streams once, the
     per-output-channel ``scales`` multiply the f32 accumulator before
@@ -126,10 +137,11 @@ def nm_spmm_pallas_decode_q(
     if scales.shape != (vals.shape[1],):
         raise ValueError(
             f"scales shape {scales.shape} != (N,) = ({vals.shape[1]},)")
-    return _pallas_decode(x, vals, idx, scales, bias, cfg=cfg,
-                          block_n=block_n, block_k=block_k,
-                          activation=activation, out_dtype=out_dtype,
-                          interpret=interpret)
+    run = functools.partial(_pallas_decode, cfg=cfg, block_n=block_n,
+                            block_k=block_k, activation=activation,
+                            out_dtype=out_dtype, interpret=interpret)
+    return named_kernel(run, "nm_spmm_pallas_decode_q", name)(
+        x, vals, idx, scales, bias)
 
 
 def _pallas_decode(x, vals, idx, scales, bias, *, cfg, block_n, block_k,

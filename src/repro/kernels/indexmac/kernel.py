@@ -22,10 +22,16 @@ TPU adaptation of the paper's vindexmac + B-stationary dataflow
 
 Accumulation is fp32 in a VMEM scratch buffer, output written on the last
 k step.
+
+A call may carry a ``name`` (the model's ``<target>_<projection>``, e.g.
+``ffn_w_up``): the kernel then runs under a jit of its own, and its
+device op reads ``nm_spmm_pallas_<name>.N`` in a profile instead of the
+bare family name (see :func:`named_kernel`).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +46,22 @@ from repro.core.sparsity import NMConfig
 # at the default (bk, bn) = (2048, 256) take ~17 MiB, over Mosaic's
 # 16 MiB default; 32 MiB fits in every TPU generation's VMEM
 VMEM_LIMIT = 32 * 2**20
+
+
+def named_kernel(run, family: str, name):
+    """``run`` itself when ``name`` is None, else ``run`` under a jit
+    called ``<family>_<name>``. XLA names a kernel's device op after the
+    innermost jitted function around it, so a profile then reads
+    ``<family>_<name>.N`` for this call, whatever the rest of the program
+    holds; ``<family>`` stays a prefix of every name of the family."""
+    if name is None:
+        return run
+
+    def call(*args):
+        return run(*args)
+
+    call.__name__ = call.__qualname__ = f"{family}_{name}"
+    return jax.jit(call)
 
 
 def check_pattern(cfg: NMConfig) -> None:
@@ -131,7 +153,8 @@ def _nm_spmm_q_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("cfg", "block_m", "block_n", "block_k", "out_dtype", "interpret"),
+    static_argnames=("cfg", "block_m", "block_n", "block_k", "out_dtype",
+                     "interpret", "name"),
 )
 def nm_spmm_pallas_q(
     x: jax.Array,
@@ -145,12 +168,21 @@ def nm_spmm_pallas_q(
     block_k: int = 2048,
     out_dtype=None,
     interpret: bool = False,
+    name: Optional[str] = None,
 ) -> jax.Array:
     """y = (x @ decompress(int8 vals, idx)) * scales[col].
 
     Same tiling contract as :func:`nm_spmm_pallas`; additionally
     ``vals`` must be int8 and ``scales`` float32 of shape (N,).
     """
+    run = functools.partial(
+        _spmm_q, cfg=cfg, block_m=block_m, block_n=block_n, block_k=block_k,
+        out_dtype=out_dtype, interpret=interpret)
+    return named_kernel(run, "nm_spmm_pallas_q", name)(x, vals, idx, scales)
+
+
+def _spmm_q(x, vals, idx, scales, *, cfg, block_m, block_n, block_k,
+            out_dtype, interpret):
     mm, kk = x.shape
     kc, nn = vals.shape
     if kc * cfg.m != kk * cfg.n:
@@ -201,7 +233,8 @@ def nm_spmm_pallas_q(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("cfg", "block_m", "block_n", "block_k", "out_dtype", "interpret"),
+    static_argnames=("cfg", "block_m", "block_n", "block_k", "out_dtype",
+                     "interpret", "name"),
 )
 def nm_spmm_pallas(
     x: jax.Array,
@@ -214,12 +247,21 @@ def nm_spmm_pallas(
     block_k: int = 2048,
     out_dtype=None,
     interpret: bool = False,
+    name: Optional[str] = None,
 ) -> jax.Array:
     """y = x @ decompress(vals, idx). See module docstring.
 
     Shape requirements (enforced): M % block_m == 0, N % block_n == 0,
     K % block_k == 0 (block_k clamped to K), block_k % m == 0.
     """
+    run = functools.partial(
+        _spmm, cfg=cfg, block_m=block_m, block_n=block_n, block_k=block_k,
+        out_dtype=out_dtype, interpret=interpret)
+    return named_kernel(run, "nm_spmm_pallas", name)(x, vals, idx)
+
+
+def _spmm(x, vals, idx, *, cfg, block_m, block_n, block_k, out_dtype,
+          interpret):
     mm, kk = x.shape
     kc, nn = vals.shape
     if kc * cfg.m != kk * cfg.n:

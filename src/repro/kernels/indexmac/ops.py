@@ -197,13 +197,14 @@ def run_pallas_padded(
     cfg: NMConfig,
     plan: PadPlan,
     interpret: bool,
+    name: Optional[str] = None,
 ) -> jax.Array:
     """Pad operands to the plan, run the kernel, slice the logical output."""
     xp, vp, ip = pad_nm_operands(x2, vals, idx, plan, cfg)
     bm, bn, bk = plan.block
     y = nm_spmm_pallas(
         xp, vp, ip, cfg=cfg, block_m=bm, block_n=bn, block_k=bk,
-        interpret=interpret,
+        interpret=interpret, name=name,
     )
     return y[: plan.m, : plan.n]
 
@@ -211,14 +212,14 @@ def run_pallas_padded(
 @registry.register("nm_matmul", "pallas_padded", priority=100,
                    supports=_tpu(_pallas_supports), uses_plan=True,
                    backend="tpu")
-def _run_pallas_impl(x2, vals, idx, *, cfg, plan, interpret):
+def _run_pallas_impl(x2, vals, idx, *, cfg, plan, interpret, name=None):
     return run_pallas_padded(
-        x2, vals, idx, cfg=cfg, plan=plan, interpret=interpret
+        x2, vals, idx, cfg=cfg, plan=plan, interpret=interpret, name=name
     )
 
 
 @registry.register("nm_matmul", "reference", priority=0)
-def _run_ref_impl(x2, vals, idx, *, cfg, plan, interpret):
+def _run_ref_impl(x2, vals, idx, *, cfg, plan, interpret, name=None):
     return nm_matmul_ref(x2, vals, idx, cfg)
 
 
@@ -231,6 +232,7 @@ def run_pallas_padded_q(
     cfg: NMConfig,
     plan: PadPlan,
     interpret: bool,
+    name: Optional[str] = None,
 ) -> jax.Array:
     """Quantized sibling of :func:`run_pallas_padded`: pads the int8
     operands (appended columns get unit scales — they are sliced away)
@@ -242,7 +244,7 @@ def run_pallas_padded_q(
     bm, bn, bk = plan.block
     y = nm_spmm_pallas_q(
         xp, vp, ip, sp, cfg=cfg, block_m=bm, block_n=bn, block_k=bk,
-        interpret=interpret,
+        interpret=interpret, name=name,
     )
     return y[: plan.m, : plan.n]
 
@@ -250,14 +252,17 @@ def run_pallas_padded_q(
 @registry.register("nm_matmul_q", "pallas_padded_q", priority=100,
                    supports=_tpu(_pallas_supports), uses_plan=True,
                    backend="tpu")
-def _run_pallas_q_impl(x2, vals, idx, scales, *, cfg, plan, interpret):
+def _run_pallas_q_impl(x2, vals, idx, scales, *, cfg, plan, interpret,
+                       name=None):
     return run_pallas_padded_q(
-        x2, vals, idx, scales, cfg=cfg, plan=plan, interpret=interpret
+        x2, vals, idx, scales, cfg=cfg, plan=plan, interpret=interpret,
+        name=name,
     )
 
 
 @registry.register("nm_matmul_q", "reference_q", priority=0)
-def _run_ref_q_impl(x2, vals, idx, scales, *, cfg, plan, interpret):
+def _run_ref_q_impl(x2, vals, idx, scales, *, cfg, plan, interpret,
+                    name=None):
     return nm_matmul_q_ref(x2, vals, idx, scales, cfg)
 
 
@@ -276,6 +281,7 @@ def run_pallas_decode(
     plan: PadPlan,
     activation: Optional[str],
     interpret: bool,
+    name: Optional[str] = None,
 ) -> jax.Array:
     """Pad to the plan and run the fused decode kernel. Padded bias
     columns are zero and all epilogue activations fix 0 (act(0) == 0 for
@@ -287,7 +293,7 @@ def run_pallas_decode(
     _, bn, bk = plan.block
     y = nm_spmm_pallas_decode(
         xp, vp, ip, bp, cfg=cfg, block_n=bn, block_k=bk,
-        activation=activation, interpret=interpret,
+        activation=activation, interpret=interpret, name=name,
     )
     return y[: plan.m, : plan.n]
 
@@ -296,16 +302,16 @@ def run_pallas_decode(
                    supports=_tpu(_decode_supports), uses_plan=True,
                    backend="tpu")
 def _run_pallas_decode_impl(x2, vals, idx, bias, *, cfg, plan, activation,
-                            interpret):
+                            interpret, name=None):
     return run_pallas_decode(
         x2, vals, idx, bias, cfg=cfg, plan=plan, activation=activation,
-        interpret=interpret,
+        interpret=interpret, name=name,
     )
 
 
 @registry.register("nm_matmul_decode", "reference_decode", priority=0)
 def _run_ref_decode_impl(x2, vals, idx, bias, *, cfg, plan, activation,
-                         interpret):
+                         interpret, name=None):
     w = decompress_nm(vals, idx, cfg, axis=0)
     y32 = jnp.dot(
         x2.astype(jnp.float32), w.astype(jnp.float32),
@@ -325,6 +331,7 @@ def run_pallas_decode_q(
     plan: PadPlan,
     activation: Optional[str],
     interpret: bool,
+    name: Optional[str] = None,
 ) -> jax.Array:
     """int8 decode sibling: padded columns get unit scales + zero bias."""
     xp, vp, ip = pad_nm_operands(x2, vals, idx, plan, cfg)
@@ -336,7 +343,7 @@ def run_pallas_decode_q(
     _, bn, bk = plan.block
     y = nm_spmm_pallas_decode_q(
         xp, vp, ip, sp, bp, cfg=cfg, block_n=bn, block_k=bk,
-        activation=activation, interpret=interpret,
+        activation=activation, interpret=interpret, name=name,
     )
     return y[: plan.m, : plan.n]
 
@@ -345,16 +352,16 @@ def run_pallas_decode_q(
                    supports=_tpu(_decode_supports), uses_plan=True,
                    backend="tpu")
 def _run_pallas_decode_q_impl(x2, vals, idx, scales, bias, *, cfg, plan,
-                              activation, interpret):
+                              activation, interpret, name=None):
     return run_pallas_decode_q(
         x2, vals, idx, scales, bias, cfg=cfg, plan=plan,
-        activation=activation, interpret=interpret,
+        activation=activation, interpret=interpret, name=name,
     )
 
 
 @registry.register("nm_matmul_decode_q", "reference_decode_q", priority=0)
 def _run_ref_decode_q_impl(x2, vals, idx, scales, bias, *, cfg, plan,
-                           activation, interpret):
+                           activation, interpret, name=None):
     w8 = decompress_nm(vals, idx, cfg, axis=0)
     y32 = jnp.dot(
         x2.astype(jnp.float32), w8.astype(jnp.float32),
@@ -380,7 +387,8 @@ def _epilogue_after(y, bias, activation):
 
 def nm_matmul(x: jax.Array, w, *,
               block: Optional[tuple[int, int, int]] = None,
-              epilogue=None, backend: Optional[str] = None) -> jax.Array:
+              epilogue=None, backend: Optional[str] = None,
+              name: Optional[str] = None) -> jax.Array:
     """y = epilogue(x @ densify(w)); x: (..., K), w: an NMWeight or
     QNMWeight compressed along its axis 0 (the contraction dim).
 
@@ -395,7 +403,10 @@ def nm_matmul(x: jax.Array, w, *,
     block for this call (benchmarks); ``backend`` overrides the policy's
     backend (``"auto"``/``"tpu"``/``"gpu"`` — see
     :mod:`repro.kernels.backend`; forcing an unavailable backend raises
-    :class:`repro.kernels.registry.KernelForceError`).
+    :class:`repro.kernels.registry.KernelForceError`). ``name`` labels
+    the TPU kernel's device op (``nm_spmm_pallas[_decode]_<name>.N`` in a
+    profile; models pass ``<target>_<projection>``); it changes nothing
+    else.
     """
     bias, activation = resolve_epilogue(epilogue)
     if isinstance(w, QNMWeight):
@@ -407,7 +418,7 @@ def nm_matmul(x: jax.Array, w, *,
         return _nm_matmul_q_core(
             x, w.vals, w.idx, w.scales, bias, w.nm, activation,
             pol.mode != "off", block or pol.block,
-            block or pol.decode_block, pol.mode == "force", be)
+            block or pol.decode_block, pol.mode == "force", be, name)
     if not isinstance(w, NMWeight):
         raise TypeError(
             f"nm_matmul expects an NMWeight or QNMWeight, got "
@@ -422,7 +433,7 @@ def nm_matmul(x: jax.Array, w, *,
     return _nm_matmul_core(
         x, w.vals, w.idx, bias, w.nm, activation,
         pol.mode != "off", block or pol.block,
-        block or pol.decode_block, pol.mode == "force", be)
+        block or pol.decode_block, pol.mode == "force", be, name)
 
 
 def _check_axis0(w, name):
@@ -452,15 +463,16 @@ def nm_matmul_q(x: jax.Array, w: QNMWeight, *,
 # logical shapes — padding and family choice never change it)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
 def _nm_matmul_core(x, vals, idx, bias, cfg, activation, use_kernel, block,
-                    decode_block, force, backend):
+                    decode_block, force, backend, name):
     return _core_fwd_impl(x, vals, idx, bias, cfg, activation, use_kernel,
-                          block, decode_block, force, backend)
+                          block, decode_block, force, backend, name)
 
 
 def _core_fwd_impl(x, vals, idx, bias, cfg, activation, use_kernel, block,
-                   decode_block, force, backend):
+                   decode_block, force, backend, name):
     vals, idx = _pin_compressed(vals, idx)
     lead = x.shape[:-1]
     k = x.shape[-1]
@@ -476,25 +488,26 @@ def _core_fwd_impl(x, vals, idx, bias, cfg, activation, use_kernel, block,
         y2 = registry.dispatch(
             op, ctx, x2, vals, idx, bias,
             cfg=cfg, plan=plan, activation=activation, interpret=interp,
+            name=name,
         )
     else:
         y2 = registry.dispatch(
             op, ctx, x2, vals, idx,
-            cfg=cfg, plan=plan, interpret=interp,
+            cfg=cfg, plan=plan, interpret=interp, name=name,
         )
         y2 = _epilogue_after(y2, bias, activation)
     return y2.reshape(*lead, nn)
 
 
 def _core_fwd(x, vals, idx, bias, cfg, activation, use_kernel, block,
-              decode_block, force, backend):
+              decode_block, force, backend, name):
     y = _core_fwd_impl(x, vals, idx, bias, cfg, activation, use_kernel,
-                       block, decode_block, force, backend)
+                       block, decode_block, force, backend, name)
     return y, (x, vals, idx, bias)
 
 
 def _core_bwd(cfg, activation, use_kernel, block, decode_block, force,
-              backend, res, dy):
+              backend, name, res, dy):
     x, vals, idx, bias = res
 
     def ref(x_, vals_, bias_):
@@ -524,7 +537,7 @@ _nm_matmul_core.defvjp(_core_fwd, _core_bwd)
 
 
 def _nm_matmul_q_core(x, vals, idx, scales, bias, cfg, activation,
-                      use_kernel, block, decode_block, force, backend):
+                      use_kernel, block, decode_block, force, backend, name):
     vals, idx = _pin_compressed(vals, idx)
     lead = x.shape[:-1]
     k = x.shape[-1]
@@ -540,11 +553,12 @@ def _nm_matmul_q_core(x, vals, idx, scales, bias, cfg, activation,
         y2 = registry.dispatch(
             op, ctx, x2, vals, idx, scales, bias,
             cfg=cfg, plan=plan, activation=activation, interpret=interp,
+            name=name,
         )
     else:
         y2 = registry.dispatch(
             op, ctx, x2, vals, idx, scales,
-            cfg=cfg, plan=plan, interpret=interp,
+            cfg=cfg, plan=plan, interpret=interp, name=name,
         )
         y2 = _epilogue_after(y2, bias, activation)
     return y2.reshape(*lead, nn)

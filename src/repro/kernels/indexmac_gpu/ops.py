@@ -17,6 +17,9 @@ autotune block lookup) is shared with the TPU path byte for byte:
   (its sublane/lane granularity is TPU-motivated but GPU-legal, and
   keeping one geometry means one autotune cache schema and bit-exact
   parity fixtures across backends).
+
+The ``name`` the op layer passes (a kernel label for TPU profiles) is
+accepted and not used here: the GPU kernels keep their family names.
 """
 from __future__ import annotations
 
@@ -72,7 +75,7 @@ def run_gpu_padded(
 @registry.register("nm_matmul", "pallas_gpu", priority=100,
                    supports=_pallas_supports, uses_plan=True,
                    backend="gpu")
-def _run_gpu_impl(x2, vals, idx, *, cfg, plan, interpret):
+def _run_gpu_impl(x2, vals, idx, *, cfg, plan, interpret, name=None):
     return run_gpu_padded(
         x2, vals, idx, cfg=cfg, plan=plan, interpret=interpret
     )
@@ -104,7 +107,8 @@ def run_gpu_padded_q(
 @registry.register("nm_matmul_q", "pallas_gpu_q", priority=100,
                    supports=_pallas_supports, uses_plan=True,
                    backend="gpu")
-def _run_gpu_q_impl(x2, vals, idx, scales, *, cfg, plan, interpret):
+def _run_gpu_q_impl(x2, vals, idx, scales, *, cfg, plan, interpret,
+                    name=None):
     return run_gpu_padded_q(
         x2, vals, idx, scales, cfg=cfg, plan=plan, interpret=interpret
     )
@@ -145,7 +149,7 @@ def run_gpu_decode(
                    supports=_decode_supports, uses_plan=True,
                    backend="gpu")
 def _run_gpu_decode_impl(x2, vals, idx, bias, *, cfg, plan, activation,
-                         interpret):
+                         interpret, name=None):
     return run_gpu_decode(
         x2, vals, idx, bias, cfg=cfg, plan=plan, activation=activation,
         interpret=interpret,
@@ -183,7 +187,7 @@ def run_gpu_decode_q(
                    supports=_decode_supports, uses_plan=True,
                    backend="gpu")
 def _run_gpu_decode_q_impl(x2, vals, idx, scales, bias, *, cfg, plan,
-                           activation, interpret):
+                           activation, interpret, name=None):
     return run_gpu_decode_q(
         x2, vals, idx, scales, bias, cfg=cfg, plan=plan,
         activation=activation, interpret=interpret,

@@ -349,10 +349,13 @@ def gqa_apply(
     positions = view.positions
     if positions is None:
         positions = jnp.arange(s)
-    q = linear_apply(params["wq"], x).reshape(b, s, cfg.q_heads, cfg.head_dim)
+    q = linear_apply(params["wq"], x, name="attn_proj_wq").reshape(
+        b, s, cfg.q_heads, cfg.head_dim)
     if cross_kv is None:
-        k = linear_apply(params["wk"], x).reshape(b, s, cfg.kv_heads, cfg.head_dim)
-        v = linear_apply(params["wv"], x).reshape(b, s, cfg.kv_heads, cfg.head_dim)
+        k = linear_apply(params["wk"], x, name="attn_proj_wk").reshape(
+            b, s, cfg.kv_heads, cfg.head_dim)
+        v = linear_apply(params["wv"], x, name="attn_proj_wv").reshape(
+            b, s, cfg.kv_heads, cfg.head_dim)
     else:
         k, v = cross_kv
     if "q_norm" in params:
@@ -368,16 +371,18 @@ def gqa_apply(
         assert cache is not None and cache_len is not None
         if block_table is not None:
             assert write_mask is not None
-            k_cache = paged_write(cache["k"], k, cache_len,
-                                  block_table, write_mask)
-            v_cache = paged_write(cache["v"], v, cache_len,
-                                  block_table, write_mask)
+            with jax.named_scope("kv_write"):
+                k_cache = paged_write(cache["k"], k, cache_len,
+                                      block_table, write_mask)
+                v_cache = paged_write(cache["v"], v, cache_len,
+                                      block_table, write_mask)
             new_cache = {"k": k_cache, "v": v_cache}
             k_view = paged_gather(k_cache, block_table)
             v_view = paged_gather(v_cache, block_table)
         else:
-            k_view = k_cache = _write_cache(cache["k"], k, cache_len)
-            v_view = v_cache = _write_cache(cache["v"], v, cache_len)
+            with jax.named_scope("kv_write"):
+                k_view = k_cache = _write_cache(cache["k"], k, cache_len)
+                v_view = v_cache = _write_cache(cache["v"], v, cache_len)
             new_cache = {"k": k_cache, "v": v_cache}
         # chunk (multi-token prefill piece): causal masking via absolute
         # query positions; decode (s=1) keeps the plain length mask.
@@ -408,18 +413,19 @@ def gqa_apply(
         )
     if mode == "prefill" and cross_kv is None:
         assert cache is not None
-        k_cache = jax.lax.dynamic_update_slice(
-            cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0)
-        )
-        v_cache = jax.lax.dynamic_update_slice(
-            cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0)
-        )
+        with jax.named_scope("kv_write"):
+            k_cache = jax.lax.dynamic_update_slice(
+                cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0)
+            )
+            v_cache = jax.lax.dynamic_update_slice(
+                cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0)
+            )
         new_cache = {"k": k_cache, "v": v_cache}
     # wo is row-parallel under TP serving (local heads in, full d_model
     # out): per-shard output is a partial sum — reduced here only when the
     # serving engine declared the in-axis sharded, identity elsewhere
-    y = tp_reduce(linear_apply(params["wo"], out.reshape(b, s, -1)),
-                  "attn_out")
+    y = tp_reduce(linear_apply(params["wo"], out.reshape(b, s, -1),
+                               name="attn_proj_wo"), "attn_out")
     return y, new_cache
 
 
@@ -482,10 +488,12 @@ def _mla_q(params, x, cfg, positions, rope_theta):
     h = cfg.q_heads
     qk_dim = cfg.nope_head_dim + cfg.rope_head_dim
     if "wq_a" in params:
-        cq = rmsnorm_apply(params["q_a_norm"], linear_apply(params["wq_a"], x))
-        q = linear_apply(params["wq_b"], cq)
+        cq = rmsnorm_apply(params["q_a_norm"],
+                           linear_apply(params["wq_a"], x,
+                                        name="attn_proj_wq_a"))
+        q = linear_apply(params["wq_b"], cq, name="attn_proj_wq_b")
     else:
-        q = linear_apply(params["wq"], x)
+        q = linear_apply(params["wq"], x, name="attn_proj_wq")
     q = q.reshape(b, s, h, qk_dim)
     q_nope = q[..., : cfg.nope_head_dim]
     q_rope = apply_rope(q[..., cfg.nope_head_dim:], positions, rope_theta)
@@ -526,9 +534,12 @@ def mla_apply(
         positions = jnp.arange(s)
     h = cfg.q_heads
     q_nope, q_rope = _mla_q(params, x, cfg, positions, rope_theta)
-    ckv = rmsnorm_apply(params["kv_a_norm"], linear_apply(params["wkv_a"], x))
+    ckv = rmsnorm_apply(params["kv_a_norm"],
+                        linear_apply(params["wkv_a"], x,
+                                     name="attn_proj_wkv_a"))
     kr = apply_rope(
-        linear_apply(params["wk_rope"], x)[:, :, None, :], positions, rope_theta
+        linear_apply(params["wk_rope"], x, name="attn_proj_wk_rope")
+        [:, :, None, :], positions, rope_theta
     )[:, :, 0, :]  # (b, s, rope_dim), shared across heads
 
     w_uk = params["w_uk"].astype(q_nope.dtype)  # (h, lora, nope)
@@ -540,16 +551,18 @@ def mla_apply(
         assert cache is not None and cache_len is not None
         if block_table is not None:
             assert write_mask is not None
-            ckv_c = paged_write(cache["ckv"], ckv, cache_len,
-                                block_table, write_mask)
-            kr_c = paged_write(cache["kr"], kr, cache_len,
-                               block_table, write_mask)
+            with jax.named_scope("kv_write"):
+                ckv_c = paged_write(cache["ckv"], ckv, cache_len,
+                                    block_table, write_mask)
+                kr_c = paged_write(cache["kr"], kr, cache_len,
+                                   block_table, write_mask)
             new_cache = {"ckv": ckv_c, "kr": kr_c}
             ckv_v = paged_gather(ckv_c, block_table)
             kr_v = paged_gather(kr_c, block_table)
         else:
-            ckv_v = ckv_c = _write_cache(cache["ckv"], ckv, cache_len)
-            kr_v = kr_c = _write_cache(cache["kr"], kr, cache_len)
+            with jax.named_scope("kv_write"):
+                ckv_v = ckv_c = _write_cache(cache["ckv"], ckv, cache_len)
+                kr_v = kr_c = _write_cache(cache["kr"], kr, cache_len)
             new_cache = {"ckv": ckv_c, "kr": kr_c}
         # absorbed attention over the compressed cache (MLA decode):
         #   logits = q_nope W_uk . ckv + q_rope . kr
@@ -609,15 +622,17 @@ def mla_apply(
             )
         if mode == "prefill":
             assert cache is not None
-            ckv_c = jax.lax.dynamic_update_slice(
-                cache["ckv"], ckv.astype(cache["ckv"].dtype), (0, 0, 0)
-            )
-            kr_c = jax.lax.dynamic_update_slice(
-                cache["kr"], kr.astype(cache["kr"].dtype), (0, 0, 0)
-            )
+            with jax.named_scope("kv_write"):
+                ckv_c = jax.lax.dynamic_update_slice(
+                    cache["ckv"], ckv.astype(cache["ckv"].dtype), (0, 0, 0)
+                )
+                kr_c = jax.lax.dynamic_update_slice(
+                    cache["kr"], kr.astype(cache["kr"].dtype), (0, 0, 0)
+                )
             new_cache = {"ckv": ckv_c, "kr": kr_c}
     y = tp_reduce(
-        linear_apply(params["wo"], out.reshape(b, s, h * cfg.v_head_dim)),
+        linear_apply(params["wo"], out.reshape(b, s, h * cfg.v_head_dim),
+                     name="attn_proj_wo"),
         "attn_out")
     return y, new_cache
 

@@ -103,6 +103,7 @@ def linear_apply(
     *,
     compute_dtype=None,
     epilogue: Optional[Epilogue] = None,
+    name: Optional[str] = None,
 ) -> jax.Array:
     """y = epilogue(x @ W). Dispatches on the weight node's type:
     NMWeight goes to the indexmac kernel path (its own nm/policy),
@@ -115,16 +116,21 @@ def linear_apply(
     types — decode-shaped calls fuse it into the kernel writeback — and
     is applied with the identical f32 composition for the dense/masked
     kinds, so swapping a layer's weight representation never changes the
-    epilogue arithmetic."""
+    epilogue arithmetic.
+
+    ``name`` (``<target>_<projection>``, e.g. ``ffn_w_down``) labels the
+    compressed types' kernel in a profile, as in
+    ``nm_spmm_pallas_decode_ffn_w_down.N``; the other kinds ignore it."""
     compute_dtype = compute_dtype or get_compute_dtype()
     xc = x.astype(compute_dtype)
     if isinstance(params, QNMWeight):
         # int8 payload stays int8 — dequantization happens in-register
         # inside the kernel (scales at accumulator writeback); only the
         # activation follows the compute dtype.
-        return nm_matmul(xc, params, epilogue=epilogue)
+        return nm_matmul(xc, params, epilogue=epilogue, name=name)
     if isinstance(params, NMWeight):
-        return nm_matmul(xc, params.astype(compute_dtype), epilogue=epilogue)
+        return nm_matmul(xc, params.astype(compute_dtype), epilogue=epilogue,
+                         name=name)
     if isinstance(params, MaskedNMWeight):
         # re-project every forward; gradients flow to all entries
         # (straight-through), pruned entries can revive.

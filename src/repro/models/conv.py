@@ -126,7 +126,8 @@ def conv2d(
     (reference vs Pallas, block triple, float vs int8 family) follows the
     weight's own metadata, exactly as for a linear layer.
     """
-    patches = im2col(x, kh, kw, stride=stride, padding=padding)
+    with jax.named_scope("im2col"):
+        patches = im2col(x, kh, kw, stride=stride, padding=padding)
     return linear_apply(w, patches, compute_dtype=compute_dtype)
 
 

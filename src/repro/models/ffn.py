@@ -41,23 +41,28 @@ def ffn_apply(
     params: dict,
     x: jax.Array,
     cfg: FFNConfig,
+    *,
+    target: str = "ffn",
 ) -> jax.Array:
+    """``target`` is the sparsity target the weights were built under
+    (``ffn_init``'s); it only names the kernels (``<target>_w_up``, ...)."""
     # the activation rides as an Epilogue on its projection: decode-shaped
     # sparse GEMMs fuse it into the kernel writeback (one launch), every
     # other path applies the identical f32 composition after the GEMM.
     if cfg.act in ("swiglu", "geglu"):
-        up = linear_apply(params["w_up"], x)
+        up = linear_apply(params["w_up"], x, name=f"{target}_w_up")
         act = "silu" if cfg.act == "swiglu" else "gelu"
         gate = linear_apply(params["w_gate"], x,
-                            epilogue=Epilogue(activation=act))
+                            epilogue=Epilogue(activation=act),
+                            name=f"{target}_w_gate")
         h = gate * up
-    elif cfg.act == "gelu":
-        h = linear_apply(params["w_up"], x, epilogue=Epilogue(activation="gelu"))
-    elif cfg.act == "relu_sq":
+    elif cfg.act in ("gelu", "relu_sq"):
         h = linear_apply(params["w_up"], x,
-                         epilogue=Epilogue(activation="relu_sq"))
+                         epilogue=Epilogue(activation=cfg.act),
+                         name=f"{target}_w_up")
     else:
         raise ValueError(cfg.act)
     # w_down is row-parallel under TP serving: per-shard output is a
     # partial sum over the sharded d_ff — reduced here, identity elsewhere
-    return tp_reduce(linear_apply(params["w_down"], h), "ffn_down")
+    return tp_reduce(linear_apply(params["w_down"], h,
+                                  name=f"{target}_w_down"), "ffn_down")

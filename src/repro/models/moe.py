@@ -66,7 +66,8 @@ def moe_init(
 def _expert_ffn(params, xe: jax.Array, cfg: MoEConfig):
     """xe: (E, C, D) -> (E, C, D), vmapped over the expert axis."""
     fcfg = FFNConfig(d_ff=cfg.d_expert, act=cfg.act)
-    return jax.vmap(lambda pp, xx: ffn_apply(pp, xx, fcfg))(params, xe)
+    return jax.vmap(lambda pp, xx: ffn_apply(pp, xx, fcfg, target="expert"))(
+        params, xe)
 
 
 def moe_apply(
@@ -137,6 +138,7 @@ def _moe_apply_local(
         y = y + ffn_apply(
             params["shared"], xf,
             FFNConfig(d_ff=cfg.n_shared * cfg.d_expert, act=cfg.act),
+            target="expert",
         )
 
     # load-balance aux loss (Switch-style): E * sum_e f_e * P_e
@@ -241,7 +243,7 @@ def _moe_apply_shard_map(params, x, cfg: MoEConfig, mesh):
             y = y + ffn_apply(
                 shared, xf,
                 FFNConfig(d_ff=cfg.n_shared * cfg.d_expert // tp_size,
-                          act=cfg.act))
+                          act=cfg.act), target="expert")
 
         y = jax.lax.psum(y, "model")
 

@@ -127,11 +127,12 @@ def block_apply(
         mixer_cache = {k: v for k, v in cache.items()
                        if not k.startswith("cross_")} or None
     if isinstance(mx, AttnConfig):
-        y, new_mc = attention.attn_apply(
-            params["mixer"], h, mx, view=view, cache=mixer_cache,
-            rope_theta=mx.rope_theta or cfg.rope_theta,
-            chunk=cfg.attn_chunk,
-        )
+        with jax.named_scope("attention"):
+            y, new_mc = attention.attn_apply(
+                params["mixer"], h, mx, view=view, cache=mixer_cache,
+                rope_theta=mx.rope_theta or cfg.rope_theta,
+                chunk=cfg.attn_chunk,
+            )
     elif isinstance(mx, MambaConfig):
         y, new_mc = mamba.mamba_apply(params["mixer"], h, mx, mode=mode,
                                       cache=mixer_cache)
@@ -182,10 +183,11 @@ def block_apply(
             new_cache["cm_last"] = cm_last.astype(new_cache["cm_last"].dtype)
     elif block.mlp is not None:
         hm = rmsnorm_apply(params["norm2"], x, cfg.norm_eps)
-        if isinstance(block.mlp, MoEConfig):
-            y2, aux = moe.moe_apply(params["mlp"], hm, block.mlp)
-        else:
-            y2 = ffn_apply(params["mlp"], hm, block.mlp)
+        with jax.named_scope("ffn"):
+            if isinstance(block.mlp, MoEConfig):
+                y2, aux = moe.moe_apply(params["mlp"], hm, block.mlp)
+            else:
+                y2 = ffn_apply(params["mlp"], hm, block.mlp)
         x = x + y2
     x = shard_hint(x, ("pod", "data"), None, None)
     return x, new_cache, aux
@@ -434,15 +436,16 @@ class LM:
             new_caches.append(new_c)
             aux_total = aux_total + aux
 
-        x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
-        if cfg.tie_embeddings:
-            logits = embedding_attend(params["embed"], x)
-        else:
-            logits = linear_apply(params["lm_head"], x,
-                                  compute_dtype=jnp.float32)
-        if cfg.logit_softcap:
-            c = cfg.logit_softcap
-            logits = jnp.tanh(logits / c) * c
+        with jax.named_scope("head"):
+            x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
+            if cfg.tie_embeddings:
+                logits = embedding_attend(params["embed"], x)
+            else:
+                logits = linear_apply(params["lm_head"], x,
+                                      compute_dtype=jnp.float32)
+            if cfg.logit_softcap:
+                c = cfg.logit_softcap
+                logits = jnp.tanh(logits / c) * c
         return logits, new_caches, aux_total
 
     # ---- loss ------------------------------------------------------------

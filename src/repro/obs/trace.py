@@ -55,21 +55,25 @@ def trace_capacity() -> int:
 
 
 class _SpanCtx:
-    """Context manager emitting a matched B/E pair around a block."""
+    """Context manager emitting a matched B/E pair around a block, inside
+    a profiler annotation of the same name."""
 
-    __slots__ = ("_tracer", "_name", "_args")
+    __slots__ = ("_tracer", "_name", "_args", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
         self._name = name
         self._args = args
+        self._annotation = tracer._annotate(name)
 
     def __enter__(self):
+        self._annotation.__enter__()
         self._tracer._emit("B", self._name, args=self._args)
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self._tracer._emit("E", self._name)
+        self._annotation.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -84,6 +88,12 @@ class Tracer:
         self._t0 = time.perf_counter()
         self._pid = os.getpid()
         self._dropped = 0
+        # the profiler's host-side span: it records nothing unless a
+        # profile is being taken (imported here, so that importing
+        # repro.obs alone loads no jax)
+        from jax.profiler import TraceAnnotation
+
+        self._annotate = TraceAnnotation
 
     # ---- emission ---------------------------------------------------------
 
@@ -109,7 +119,8 @@ class Tracer:
 
     def span(self, name: str, **args) -> _SpanCtx:
         """``with tracer.span("engine.decode", slots=3): ...`` — emits a
-        matched B/E pair on this thread."""
+        matched B/E pair on this thread, and the span ``name`` (without
+        its args) on the profiler's host line."""
         return _SpanCtx(self, name, args)
 
     def instant(self, name: str, **args) -> None:

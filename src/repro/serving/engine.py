@@ -79,7 +79,8 @@ def merge_cache_slots(new: Any, old: Any, keep: jax.Array) -> Any:
         shape[ax] = n.shape[ax]
         return jnp.where(keep.reshape(shape), n, o)
 
-    return jax.tree_util.tree_map_with_path(one, new, old)
+    with jax.named_scope("kv_write"):
+        return jax.tree_util.tree_map_with_path(one, new, old)
 
 
 def make_serve_steps(lm: LM, *, jit: bool = True):
@@ -313,57 +314,25 @@ class ServeEngine:
     def step(self) -> None:
         """One engine step: (batched, possibly chunked) prefill for every
         slot with pending prompt pieces, then one decode for every slot
-        whose prefill completed."""
-        sched = self.scheduler
+        whose prefill completed.
+
+        With observability on, the step is the ``engine.step`` span
+        (slots occupied and queue depth as it starts), and each phase a
+        child span: ``engine.plan`` (the scheduler's plan),
+        ``engine.prefill`` / ``engine.decode`` (the device call, synced
+        on the sampled tokens) holding ``engine.upload``,
+        ``engine.dispatch`` and ``engine.readback``, then
+        ``engine.finish`` (the scheduler's bookkeeping)."""
         obs = self.obs
-        span = obs.tracer.span if obs is not None else _obs.null_span()
-        pf = sched.plan_prefill()
-        if pf is not None:
-            # paged: snapshot the block table AFTER planning — admission
-            # just assigned pages for the newly admitted slots
-            tbl = ((jnp.asarray(self.page_manager.table),)
-                   if self.paged else ())
-            with span("engine.prefill", step=self.steps,
-                      active=len(pf.active), finishing=len(pf.finishing)):
-                toks, self.caches, self._key = self._prefill(
-                    self.params, jnp.asarray(pf.tokens), self.caches,
-                    jnp.asarray(pf.cache_len), *tbl,
-                    jnp.asarray(pf.mask), self._key)
-                toks_np = np.asarray(toks)  # device sync inside the span
-            sched.finish_prefill(pf, toks_np, now=time.perf_counter())
-        dc = sched.plan_decode()
-        if dc is not None:
-            # paged: plan_decode may have allocated fresh pages (or
-            # preempted a slot), so re-snapshot the table
-            tbl = ((jnp.asarray(self.page_manager.table),)
-                   if self.paged else ())
-            with span("engine.decode", step=self.steps,
-                      active=len(dc.active)):
-                toks, self.caches, self._key = self._decode(
-                    self.params, jnp.asarray(dc.tokens), self.caches,
-                    jnp.asarray(dc.lengths), *tbl,
-                    jnp.asarray(dc.mask), self._key)
-                toks_np = np.asarray(toks)  # device sync: real timestamps
-            now = time.perf_counter()
-            self.decode_times.append(now)
-            if len(self.decode_times) > 8192:  # bounded history: a
-                # long-running server must not grow a float per token
-                del self.decode_times[:4096]
-            sched.finish_decode(dc, toks_np, now=now)
-            if obs is not None:
-                obs.metrics.inc("serve_decode_steps_total")
-                obs.metrics.inc("serve_tokens_total", len(dc.active))
-        self.queue_depths.append(len(sched.queue))
-        if self.page_manager is not None:
-            self.page_utils.append(self.page_manager.utilization())
-        if len(self.queue_depths) > 8192:
-            del self.queue_depths[:4096]
-            del self.page_utils[:4096]
-        self.steps += 1
-        if obs is not None:
+        if obs is None:
+            self._step(_obs.null_span())
+            return
+        sched = self.scheduler
+        occupied = sum(1 for s in sched.slots if s.req is not None)
+        with obs.tracer.span("engine.step", step=self.steps,
+                             occupied=occupied, queue=len(sched.queue)):
+            self._step(obs.tracer.span)
             occupied = sum(1 for s in sched.slots if s.req is not None)
-            obs.tracer.instant("engine.step", step=self.steps,
-                               occupied=occupied, queue=len(sched.queue))
             obs.metrics.inc("serve_steps_total")
             obs.metrics.set_gauge("serve_slots_occupied", occupied)
             obs.metrics.set_gauge("serve_queue_depth", len(sched.queue))
@@ -375,6 +344,77 @@ class ServeEngine:
                 obs.metrics.set_gauge(
                     "prefix_hit_rate",
                     self.page_manager.stats.prefix_hit_rate)
+
+    def _step(self, span) -> None:
+        sched = self.scheduler
+        obs = self.obs
+        with span("engine.plan"):
+            pf = sched.plan_prefill()
+        if pf is not None:
+            # paged: snapshot the block table AFTER planning — admission
+            # just assigned pages for the newly admitted slots
+            tbl = self._table(span)
+            with span("engine.prefill", step=self.steps,
+                      active=len(pf.active), finishing=len(pf.finishing),
+                      rows=pf.tokens.size, real_rows=pf.real_rows):
+                toks_np = self._call(span, self._prefill, pf.tokens,
+                                     pf.cache_len, tbl, pf.mask)
+            with span("engine.finish"):
+                sched.finish_prefill(pf, toks_np, now=time.perf_counter())
+            if obs is not None:
+                obs.metrics.inc("serve_prefill_rows_total", pf.real_rows,
+                                kind="real")
+                obs.metrics.inc("serve_prefill_rows_total",
+                                pf.tokens.size - pf.real_rows, kind="pad")
+        with span("engine.plan"):
+            dc = sched.plan_decode()
+        if dc is not None:
+            # paged: plan_decode may have allocated fresh pages (or
+            # preempted a slot), so re-snapshot the table
+            tbl = self._table(span)
+            with span("engine.decode", step=self.steps,
+                      active=len(dc.active), ctx_tokens=dc.ctx_tokens):
+                toks_np = self._call(span, self._decode, dc.tokens,
+                                     dc.lengths, tbl, dc.mask)
+            with span("engine.finish"):
+                now = time.perf_counter()
+                self.decode_times.append(now)
+                if len(self.decode_times) > 8192:  # bounded history: a
+                    # long-running server must not grow a float per token
+                    del self.decode_times[:4096]
+                sched.finish_decode(dc, toks_np, now=now)
+            if obs is not None:
+                obs.metrics.inc("serve_decode_steps_total")
+                obs.metrics.inc("serve_tokens_total", len(dc.active))
+        self.queue_depths.append(len(sched.queue))
+        if self.page_manager is not None:
+            self.page_utils.append(self.page_manager.utilization())
+        if len(self.queue_depths) > 8192:
+            del self.queue_depths[:4096]
+            del self.page_utils[:4096]
+        self.steps += 1
+
+    def _table(self, span) -> tuple:
+        """The paged engine's block table on the device, as the step
+        functions' extra argument; nothing for the contiguous cache."""
+        if not self.paged:
+            return ()
+        with span("engine.upload"):
+            return (jnp.asarray(self.page_manager.table),)
+
+    def _call(self, span, fn, tokens, lengths, tbl, mask) -> np.ndarray:
+        """One device call: upload the plan's arrays, dispatch the step
+        function ``fn``, and read its sampled tokens back — the device
+        sync, so the caller's span times the device step."""
+        with span("engine.upload"):
+            tokens, lengths, mask = (jnp.asarray(tokens),
+                                     jnp.asarray(lengths), jnp.asarray(mask))
+        with span("engine.dispatch"):
+            toks, self.caches, self._key = fn(
+                self.params, tokens, self.caches, lengths, *tbl, mask,
+                self._key)
+        with span("engine.readback"):
+            return np.asarray(toks)
 
     def run(self, max_steps: int = 10_000) -> list[Request]:
         steps = 0

@@ -32,12 +32,13 @@ def make_sampler(temperature: float):
     greedy = temperature <= 0
 
     def sampler(logits: jax.Array, key: jax.Array):
-        key, sub = jax.random.split(key)
-        lf = logits.astype(jnp.float32)
-        if greedy:
-            toks = jnp.argmax(lf, axis=-1)
-        else:
-            toks = jax.random.categorical(sub, lf / temperature, axis=-1)
-        return toks.astype(jnp.int32), key
+        with jax.named_scope("sample"):
+            key, sub = jax.random.split(key)
+            lf = logits.astype(jnp.float32)
+            if greedy:
+                toks = jnp.argmax(lf, axis=-1)
+            else:
+                toks = jax.random.categorical(sub, lf / temperature, axis=-1)
+            return toks.astype(jnp.int32), key
 
     return sampler

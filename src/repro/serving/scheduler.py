@@ -83,6 +83,7 @@ class PrefillPlan:
     mask: np.ndarray       # (slots,) bool — slots whose cache rows to keep
     active: list           # slot ids prefilling this step
     finishing: list        # subset completing their final chunk
+    real_rows: int = 0     # real prompt tokens among tokens' rows
 
 
 @dataclasses.dataclass
@@ -91,6 +92,7 @@ class DecodePlan:
     lengths: np.ndarray  # (slots,) int32 — current cache lengths
     mask: np.ndarray     # (slots,) bool — slots whose cache rows to keep
     active: list         # slot ids decoding this step
+    ctx_tokens: int = 0  # sum of the active slots' cache lengths
 
 
 @dataclasses.dataclass
@@ -310,6 +312,7 @@ class Scheduler:
                     self._admitted(slot.req, i, 0, 0)
         chunk = self.prefill_chunk
         active, finishing = [], []
+        real_rows = 0
         tokens = np.zeros((self.n_slots, chunk), np.int32)
         cache_len = np.zeros(self.n_slots, np.int32)
         mask = np.zeros(self.n_slots, bool)
@@ -320,11 +323,18 @@ class Scheduler:
             cache_len[i] = slot.pos
             mask[i] = True
             active.append(i)
+            # the prompt (its last prefill_len tokens) sits right-aligned
+            # in the padded row: count its tokens inside this chunk
+            first = self.prefill_len - min(len(slot.req.prompt),
+                                           self.prefill_len)
+            real_rows += max(0, min(slot.pos + chunk, self.prefill_len)
+                             - max(slot.pos, first))
             if slot.pos + chunk >= self.prefill_len:
                 finishing.append(i)
         if not active:
             return None
-        return PrefillPlan(tokens, cache_len, mask, active, finishing)
+        return PrefillPlan(tokens, cache_len, mask, active, finishing,
+                           real_rows)
 
     def finish_prefill(self, plan: PrefillPlan, sampled: np.ndarray,
                        now: Optional[float] = None) -> None:
@@ -377,6 +387,7 @@ class Scheduler:
         lengths = np.zeros(self.n_slots, np.int32)
         mask = np.zeros(self.n_slots, bool)
         active = []
+        ctx_tokens = 0
         for i, slot in enumerate(self.slots):
             if slot.req is None or slot.pos < self.prefill_len:
                 continue
@@ -384,12 +395,13 @@ class Scheduler:
             lengths[i] = slot.length
             mask[i] = True
             active.append(i)
+            ctx_tokens += slot.length
         if not active:
             return None
         # mask gates the cache merge: a decode call must not write its
         # placeholder token-0 K/V into slots that are mid-chunked-prefill
         # (or empty) — their rows keep the pre-step cache
-        return DecodePlan(tokens, lengths, mask, active)
+        return DecodePlan(tokens, lengths, mask, active, ctx_tokens)
 
     def finish_decode(self, plan: DecodePlan, sampled: np.ndarray,
                       now: Optional[float] = None) -> None:

@@ -97,7 +97,9 @@ def test_kernel_compiles_for_v5e(one_chip, chip_compile, kind, m, k, n):
                                              "copy-start(", "pad(")), text
 
 
-def test_decode_step_compiles_for_v5e(one_chip, chip_compile):
+def _compile_yi_decode_step(one_chip):
+    """One yi-9b layer's decode step at published widths, compiled for
+    the described chip with the dispatch records it made."""
     cfg = cut_layers(get_config("yi-9b"), 1)
     cfg = dataclasses.replace(cfg, sparsity=dataclasses.replace(
         cfg.sparsity, use_kernel=True))
@@ -116,13 +118,38 @@ def test_decode_step_compiles_for_v5e(one_chip, chip_compile):
     compiled = decode_step.lower(
         params, _sds((8, 1), jnp.int32, one_chip), caches,
         _sds((8,), jnp.int32, one_chip)).compile()
-    recs = registry.dispatch_history()
+    return compiled, registry.dispatch_history()
+
+
+def test_decode_step_compiles_for_v5e(one_chip, chip_compile):
+    compiled, recs = _compile_yi_decode_step(one_chip)
     assert len(recs) == 7 and all(
         r.op == "nm_matmul_decode" and r.impl == "pallas_decode"
         and not r.interpret for r in recs), recs
     assert compiled.as_text().count("tpu_custom_call") >= 7
     # one layer's compressed weights plus embedding and head fit a chip
     assert compiled.memory_analysis().argument_size_in_bytes < 16e9
+
+
+def test_decode_kernel_ops_are_named_per_projection(one_chip, chip_compile):
+    """The chip's device ops carry each projection's name, and the
+    family prefix covers every decode kernel and nothing else: what a
+    profile of the step reads as ``nm_spmm_pallas_decode_<target>_<proj>.N``."""
+    import re
+
+    compiled, _ = _compile_yi_decode_step(one_chip)
+    kernels = [line.split("=")[0].strip().removeprefix("ROOT ")
+               for line in compiled.as_text().splitlines()
+               if "tpu_custom_call" in line and "custom-call(" in line]
+    named = sorted(re.sub(r"\.\d+$", "", k) for k in kernels)
+    assert named == sorted(
+        f"%nm_spmm_pallas_decode_{p}" for p in (
+            "attn_proj_wq", "attn_proj_wk", "attn_proj_wv", "attn_proj_wo",
+            "ffn_w_up", "ffn_w_gate", "ffn_w_down"))
+    text = compiled.as_text()
+    assert text.count("%nm_spmm_pallas_decode") == text.count(
+        "%nm_spmm_pallas_decode_attn_proj_") + text.count(
+        "%nm_spmm_pallas_decode_ffn_")
 
 
 def test_layer_init_temporaries_fit_for_v5e(one_chip, chip_compile):
