@@ -232,12 +232,12 @@ def explain_dispatch(x_shape, w, *, epilogue: Optional[Epilogue] = None,
     """The :class:`DispatchRecord` that ``nm_matmul(x, w)`` (or, for an
     axis-1 weight, ``indexmac_gather(w, b)``) *would* produce for an
     operand of shape ``x_shape`` — dispatch family, chosen kernel,
-    resolved backend, block triple and padded geometry — without
-    executing anything. ``backend`` overrides the policy's kernel
-    backend, same contract as :func:`nm_matmul`. Raises the same typed
-    errors as the real call, including :class:`KernelForceError` for a
-    forced weight whose shape cannot normalize or a forced backend this
-    host cannot execute."""
+    resolved backend, block triple, padded geometry and, for a TPU
+    decode kernel, its MXU operand dtype — without executing anything.
+    ``backend`` overrides the policy's kernel backend, same contract as
+    :func:`nm_matmul`. Raises the same typed errors as the real call,
+    including :class:`KernelForceError` for a forced weight whose shape
+    cannot normalize or a forced backend this host cannot execute."""
     return _explain_dispatch(x_shape, w, epilogue=epilogue, dtype=dtype,
                              backend=backend)
 

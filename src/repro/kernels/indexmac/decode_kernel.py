@@ -5,19 +5,35 @@ projection — shapes where the prefill kernel's (mi, ni, ki) tiling is
 all padding and XLA's dense reference wins by default. This family drops
 the M tiling:
 
-* grid is (ni, ki) — no M tiling; the whole padded x block (8, bk)
-  pins in VMEM across the entire sweep (the stationary operand).
+* grid is (ni, ki) — no M tiling; the whole padded x block, as its
+  (m, 8, bk*n/m) expansion ``x_m``, pins in VMEM across the n sweep
+  (the stationary operand).
 * ``vals``/``idx`` stream exactly once per (n, k) block, in their
-  logical layout, and expand in-register to a dense (bk, bn) tile with
-  the prefill kernel's repeat, roll and select (the vindexmac analogue: a local, bounded
-  select, never an HBM gather) before one (8, bk) x (bk, bn) dot.
+  logical layout, and never expand to a dense tile: the kernel
+  multiplies in the compressed domain (below).
 * the epilogue — dequant scales (int8 family), bias add, activation —
   runs on the f32 accumulator at writeback (see
   :mod:`repro.kernels.epilogue` for the composition contract), so a
   decode GEMM is one kernel launch end to end.
 
-Accumulation is f32 in VMEM scratch; on the integer lattice the result
-is bit-exact against the reference composition regardless of tiling.
+Compressed-domain product. Slot ``kc`` of a compressed column sits in
+M-group ``kc // n`` and holds dense row ``(kc // n) * m + idx``. With
+``x_m[j, r, kc] = x[r, (kc // n) * m + j]`` (:func:`expand_x`, built once
+per call in XLA and split by K block, a few hundred KB at most),
+
+    y = sum_j  x_m[j] @ where(idx == j, vals, 0)
+
+Each masked tile keeps the compressed (bk*n/m, bn) shape: one compare
+and one select per j, no repeat, roll or dense (bk, bn) operand — the
+vindexmac analogue, an indexed read beside the multiply. The MXU sees
+m dots of K*n/m rows each, n times a dense tile's volume, which an
+8-row x leaves far from the MXU's limit.
+
+The MXU operands are bf16 when x is bf16 and the values are bf16 or
+int8 (each masked entry is one stored value or zero, exact in bf16),
+else f32 (:func:`mxu_dtype`). Accumulation is f32 in VMEM scratch; on
+the integer lattice the result is bit-exact against the reference
+composition regardless of tiling.
 
 A call with a ``name`` (``<target>_<projection>``) shows in a profile as
 ``nm_spmm_pallas_decode_<name>.N``; ``nm_spmm_pallas_decode`` stays the
@@ -30,17 +46,42 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro import compat
 from repro.core.sparsity import NMConfig
 from repro.kernels.epilogue import ACTIVATIONS
-from repro.kernels.indexmac.kernel import (
-    check_pattern,
-    decompress_block,
-    named_kernel,
-)
+from repro.kernels.indexmac.kernel import check_pattern, named_kernel
+
+
+def mxu_dtype(x_dtype, vals_dtype):
+    """The dtype of the decode kernel's MXU operands: bf16 when x is
+    bf16 and the stored values are bf16 or int8 (both exact in bf16),
+    else f32."""
+    exact = (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.int8))
+    if (jnp.dtype(x_dtype) == jnp.bfloat16
+            and jnp.dtype(vals_dtype) in exact):
+        return jnp.dtype(jnp.bfloat16)
+    return jnp.dtype(jnp.float32)
+
+
+def expand_x(x, n: int, m: int, block_k: int):
+    """``x_m[k, j, r, c] = x[r, (kc // n) * m + j]`` for the compressed row
+    ``kc = k * bkc + c`` of K block k (``bkc = block_k * n/m``): for each
+    in-group index j, the rows of x that compressed slot kc would read if
+    its index were j, split by K block so that a kernel block is the
+    array's whole minor (M, bkc) plane for every block_k.
+
+    One gather by a constant column table: a repeat along the minor axis
+    lowers on the TPU to relayouts of a 2-wide axis, costed by XLA at an
+    order of magnitude more cycles than this gather at yi-9b's K."""
+    mm, kk = x.shape
+    bkc = block_k * n // m
+    kc = np.arange(kk * n // m).reshape(kk // block_k, 1, bkc)
+    cols = (kc // n) * m + np.arange(m)[None, :, None]  # (nk, m, bkc)
+    return jnp.take(x, cols, axis=1).transpose(1, 2, 0, 3)  # (nk, m, M, bkc)
 
 
 def _writeback(acc, o_ref, scales_ref, bias_ref, *, activation, out_dtype):
@@ -54,7 +95,7 @@ def _writeback(acc, o_ref, scales_ref, bias_ref, *, activation, out_dtype):
     o_ref[...] = y.astype(out_dtype)
 
 
-def _decode_kernel(x_ref, vals_ref, idx_ref, *rest, n, m, nk, out_dtype,
+def _decode_kernel(xm_ref, vals_ref, idx_ref, *rest, m, nk, mxu, out_dtype,
                    activation, quantized, has_bias):
     refs = list(rest)
     scales_ref = refs.pop(0) if quantized else None
@@ -66,9 +107,14 @@ def _decode_kernel(x_ref, vals_ref, idx_ref, *rest, n, m, nk, out_dtype,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = decompress_block(vals_ref[...], idx_ref[...], n, m)  # (bk, bn) f32
-    x = x_ref[...].astype(jnp.float32)
-    acc_ref[...] += jax.lax.dot(x, w, preferred_element_type=jnp.float32)
+    v = vals_ref[...].astype(jnp.float32)  # (bkc, bn)
+    ii = idx_ref[...].astype(jnp.int32)  # Mosaic compares no int8
+    acc = acc_ref[...]
+    for j in range(m):
+        w_j = jnp.where(ii == j, v, 0.0).astype(mxu)
+        acc += jax.lax.dot(xm_ref[j].astype(mxu), w_j,
+                           preferred_element_type=jnp.float32)
+    acc_ref[...] = acc
 
     @pl.when(ki == nk - 1)
     def _done():
@@ -95,7 +141,8 @@ def nm_spmm_pallas_decode(
     interpret: bool = False,
     name: Optional[str] = None,
 ) -> jax.Array:
-    """y = epilogue(x @ decompress(vals, idx)) for skinny x.
+    """y = epilogue(x @ decompress(vals, idx)) for skinny x, multiplied
+    in the compressed domain.
 
     Shape requirements (enforced): M a sublane multiple (the op layer
     pads 1..8 rows up to 8), N % block_n == 0, K % block_k == 0,
@@ -172,14 +219,14 @@ def _pallas_decode(x, vals, idx, scales, bias, *, cfg, block_n, block_k,
     quantized = scales is not None
     has_bias = bias is not None
     grid = (nn // block_n, nk)
-    # the whole (skinny) x block is index (0, k): resident across the n
-    # sweep — the stationary operand of the decode dataflow.
+    # the whole (skinny) x_m block is index (k, 0, 0, 0): resident across
+    # the n sweep — the stationary operand of the decode dataflow.
     in_specs = [
-        pl.BlockSpec((mm, block_k), lambda j, k: (0, k)),
+        pl.BlockSpec((None, cfg.m, mm, bkc), lambda j, k: (k, 0, 0, 0)),
         pl.BlockSpec((bkc, block_n), lambda j, k: (k, j)),
         pl.BlockSpec((bkc, block_n), lambda j, k: (k, j)),
     ]
-    operands = [x, vals, idx]
+    operands = [expand_x(x, cfg.n, cfg.m, block_k), vals, idx]
     if quantized:
         in_specs.append(pl.BlockSpec((1, block_n), lambda j, k: (0, j)))
         operands.append(scales.astype(jnp.float32).reshape(1, nn))
@@ -188,8 +235,9 @@ def _pallas_decode(x, vals, idx, scales, bias, *, cfg, block_n, block_k,
         operands.append(bias.astype(jnp.float32).reshape(1, nn))
 
     kernel = functools.partial(
-        _decode_kernel, n=cfg.n, m=cfg.m, nk=nk, out_dtype=out_dtype,
-        activation=activation, quantized=quantized, has_bias=has_bias,
+        _decode_kernel, m=cfg.m, nk=nk, mxu=mxu_dtype(x.dtype, vals.dtype),
+        out_dtype=out_dtype, activation=activation, quantized=quantized,
+        has_bias=has_bias,
     )
     return pl.pallas_call(
         kernel,

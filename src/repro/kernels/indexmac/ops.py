@@ -61,6 +61,7 @@ from repro.kernels import autotune, registry
 from repro.kernels.backend import interpret_for, resolve_backend
 from repro.kernels.epilogue import apply_epilogue_f32, resolve_epilogue
 from repro.kernels.indexmac.decode_kernel import (
+    mxu_dtype,
     nm_spmm_pallas_decode,
     nm_spmm_pallas_decode_q,
 )
@@ -108,11 +109,12 @@ def _validate_pair(vals, idx, k, cfg):
 # ---------------------------------------------------------------------------
 
 
-def _route(mm, nn, kk, cfg, dtype, use_kernel, force, block, decode_block,
-           quantized, backend="tpu"):
+def _route(mm, nn, kk, cfg, dtype, vals_dtype, use_kernel, force, block,
+           decode_block, quantized, backend="tpu"):
     """Resolve the dispatch family, block triple and pad plan for one
     call — shared by the executing paths and :func:`explain_dispatch`,
     so the explanation can never drift from the real routing.
+    ``dtype`` is the activation's, ``vals_dtype`` the stored values'.
     ``backend`` is the *resolved* kernel backend (never "auto") — it
     selects which lowering family the registry may pick and which
     autotune cache namespace supplies the default block."""
@@ -140,6 +142,8 @@ def _route(mm, nn, kk, cfg, dtype, use_kernel, force, block, decode_block,
     ctx = registry.make_ctx(
         (mm, kk, nn), nm=cfg, use_kernel=use_kernel, plan=plan,
         dtype=key_dtype, force=force, backend=backend,
+        mxu_dtype=(mxu_dtype(dtype, vals_dtype).name
+                   if decode and backend == "tpu" else None),
     )
     return op, plan, ctx
 
@@ -480,8 +484,8 @@ def _core_fwd_impl(x, vals, idx, bias, cfg, activation, use_kernel, block,
     mm = x2.shape[0]
     nn = vals.shape[1]
     _validate_pair(vals, idx, k, cfg)
-    op, plan, ctx = _route(mm, nn, k, cfg, x.dtype, use_kernel, force,
-                           block, decode_block, quantized=False,
+    op, plan, ctx = _route(mm, nn, k, cfg, x.dtype, vals.dtype, use_kernel,
+                           force, block, decode_block, quantized=False,
                            backend=backend)
     interp = interpret_for(backend)
     if op == "nm_matmul_decode":
@@ -545,8 +549,8 @@ def _nm_matmul_q_core(x, vals, idx, scales, bias, cfg, activation,
     mm = x2.shape[0]
     nn = vals.shape[1]
     _validate_pair(vals, idx, k, cfg)
-    op, plan, ctx = _route(mm, nn, k, cfg, x.dtype, use_kernel, force,
-                           block, decode_block, quantized=True,
+    op, plan, ctx = _route(mm, nn, k, cfg, x.dtype, vals.dtype, use_kernel,
+                           force, block, decode_block, quantized=True,
                            backend=backend)
     interp = interpret_for(backend)
     if op == "nm_matmul_decode_q":
@@ -572,8 +576,9 @@ def _nm_matmul_q_core(x, vals, idx, scales, bias, cfg, activation,
 def explain_dispatch(x_shape, w, *, epilogue=None, dtype=None, backend=None):
     """The :class:`repro.kernels.registry.DispatchRecord` that
     ``nm_matmul(x, w)`` *would* produce for an ``x`` of shape
-    ``x_shape`` — family, kernel, backend, block triple and padded
-    geometry — without running anything.
+    ``x_shape`` — family, kernel, backend, block triple, padded
+    geometry and a TPU decode kernel's ``mxu_dtype`` — without running
+    anything.
 
     ``x_shape`` is the activation shape ``(..., K)`` (for a gather-port
     weight, ``w.axis == 1``, it is the dense B operand's ``(K, N)``).
@@ -605,8 +610,9 @@ def explain_dispatch(x_shape, w, *, epilogue=None, dtype=None, backend=None):
     be = resolve_backend(
         backend if backend is not None else getattr(pol, "backend", "auto"))
     op, plan, ctx = _route(
-        mm, nn, k, w.nm, dtype, pol.mode != "off", pol.mode == "force",
-        pol.block, pol.decode_block, quantized, backend=be)
+        mm, nn, k, w.nm, dtype, w.vals.dtype, pol.mode != "off",
+        pol.mode == "force", pol.block, pol.decode_block, quantized,
+        backend=be)
     return registry.explain(op, ctx)
 
 

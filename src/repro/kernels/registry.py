@@ -58,6 +58,9 @@ class DispatchRecord:
     reason: str  # why higher-priority impls were skipped ("" if none)
     backend: str = "tpu"  # resolved kernel backend the call routed under
     interpret: bool = False  # True when a Pallas impl ran in the interpreter
+    # MXU operand dtype of a TPU decode kernel ("bfloat16" / "float32");
+    # None for every other impl
+    mxu_dtype: Optional[str] = None
 
 
 class KernelForceError(RuntimeError):
@@ -182,6 +185,7 @@ def dispatch(op: str, ctx: dict, *args, **kwargs):
                 backend=backend,
                 interpret=(impl.backend != "any"
                            and bool(kwargs.get("interpret"))),
+                mxu_dtype=_mxu_dtype(impl, ctx),
             )
         )
         return out
@@ -215,11 +219,18 @@ def explain(op: str, ctx: dict) -> DispatchRecord:
             block=plan.block if uses_plan else None,
             reason="; ".join(skipped),
             backend=backend,
+            mxu_dtype=_mxu_dtype(impl, ctx),
         )
     raise LookupError(
         f"no implementation of {op!r} supports this call on backend "
         f"{backend!r}: {'; '.join(skipped)}"
     )
+
+
+def _mxu_dtype(impl: KernelImpl, ctx: dict) -> Optional[str]:
+    """``ctx["mxu_dtype"]`` when a kernel (not a backend-neutral
+    reference) takes the call."""
+    return None if impl.backend == "any" else ctx.get("mxu_dtype")
 
 
 def _record(rec: DispatchRecord) -> None:

@@ -1,9 +1,10 @@
 """Decode-shape parity suite for the skinny-M kernel family.
 
-Every combination of M in {1, 2, 4, 7} x {f32, int8} x {2:4, 1:4} x
-{epilogue off, bias + activation} must be *bit-exact* against the
-reference composition ``activation(x @ densify(w) + bias)`` (with the
-dequant scales applied before the bias for the int8 family).
+Every combination of M in {1, 2, 4, 7} x {f32, int8, bf16, int8 values
+with bf16 x} x {2:4, 1:4, 4:8} x {epilogue off, bias + activation} must
+be *bit-exact* against the reference composition
+``activation(x @ densify(w) + bias)`` (with the dequant scales applied
+before the bias for the int8 family), cast to x's dtype.
 
 Bit-exactness is checked on the integer lattice: integer-valued
 operands keep every f32 accumulation exact regardless of summation
@@ -29,15 +30,24 @@ from repro.quant.qnmweight import QNMWeight
 
 K, N = 128, 256
 MS = (1, 2, 4, 7)
-CFGS = (NMConfig(2, 4), NMConfig(1, 4))
+CFGS = (NMConfig(2, 4), NMConfig(1, 4), NMConfig(4, 8))
+# family -> (x dtype, quantized values, the kernel's MXU operand dtype)
+FAMILIES = {
+    "f32": (jnp.float32, False, "float32"),
+    "int8": (jnp.float32, True, "float32"),
+    "bf16": (jnp.bfloat16, False, "bfloat16"),
+    "int8_bf16x": (jnp.bfloat16, True, "bfloat16"),
+}
 
 
-def _int_operands(cfg: NMConfig, m_rows: int, seed: int = 0):
-    """Integer-valued (x, weight, bias) on the exact-f32 lattice."""
-    kw = jax.random.randint(jax.random.PRNGKey(seed), (K, N), -4, 5)
-    sw = api.sparsify(kw.astype(jnp.float32), cfg, kernel_policy="force")
+def _int_operands(cfg: NMConfig, m_rows: int, seed: int = 0, k: int = K,
+                  dtype=jnp.float32, policy="force"):
+    """Integer-valued (x, weight, bias) on the exact-f32 lattice, x and
+    the weight's values in ``dtype`` (small integers are exact in bf16)."""
+    kw = jax.random.randint(jax.random.PRNGKey(seed), (k, N), -4, 5)
+    sw = api.sparsify(kw.astype(dtype), cfg, kernel_policy=policy)
     x = jax.random.randint(
-        jax.random.PRNGKey(seed + 1), (m_rows, K), -4, 5).astype(jnp.float32)
+        jax.random.PRNGKey(seed + 1), (m_rows, k), -4, 5).astype(dtype)
     bias = jax.random.randint(
         jax.random.PRNGKey(seed + 2), (N,), -3, 4).astype(jnp.float32)
     return x, sw, bias
@@ -60,17 +70,18 @@ def _reference(x, w, bias, activation):
         y32 = (x.astype(jnp.float32) @ dense) * w.scales[None, :]
     else:
         y32 = x.astype(jnp.float32) @ api.densify(w).astype(jnp.float32)
-    return apply_epilogue_f32(y32, bias, activation)
+    return apply_epilogue_f32(y32, bias, activation).astype(x.dtype)
 
 
 @pytest.mark.parametrize("cfg", CFGS, ids=lambda c: c.tag)
 @pytest.mark.parametrize("m_rows", MS)
-@pytest.mark.parametrize("family", ["f32", "int8"])
+@pytest.mark.parametrize("family", list(FAMILIES))
 @pytest.mark.parametrize("epilogue_on", [False, True],
                          ids=["plain", "fused"])
 def test_decode_kernel_bit_exact(cfg, m_rows, family, epilogue_on):
-    x, sw, bias = _int_operands(cfg, m_rows, seed=m_rows)
-    w = _quantized(sw) if family == "int8" else sw
+    dtype, quantized, mxu = FAMILIES[family]
+    x, sw, bias = _int_operands(cfg, m_rows, seed=m_rows, dtype=dtype)
+    w = _quantized(sw) if quantized else sw
     if epilogue_on:
         ep = api.Epilogue(bias=bias, activation="silu")
         ref = _reference(x, w, bias, "silu")
@@ -79,10 +90,78 @@ def test_decode_kernel_bit_exact(cfg, m_rows, family, epilogue_on):
     registry.clear_history()
     y = api.nm_matmul(x, w, epilogue=ep)
     rec = registry.last_dispatch()
-    assert rec.op == ("nm_matmul_decode_q" if family == "int8"
+    assert rec.op == ("nm_matmul_decode_q" if quantized
                       else "nm_matmul_decode")
     assert rec.impl.startswith("pallas"), rec
+    assert rec.mxu_dtype == mxu, rec
+    assert y.dtype == dtype
     np.testing.assert_array_equal(np.asarray(y), np.asarray(ref))
+
+
+@pytest.mark.parametrize("cfg", CFGS, ids=lambda c: c.tag)
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_decode_kernel_bit_exact_over_k_blocks(cfg, family):
+    """K = 2 x block_k: the accumulator carries across K blocks and the
+    x_m block follows the k index."""
+    dtype, quantized, _ = FAMILIES[family]
+    block_k = 128
+    policy = api.KernelPolicy("force", decode_block=(8, 128, block_k))
+    x, sw, bias = _int_operands(cfg, 5, seed=43, k=2 * block_k,
+                                dtype=dtype, policy=policy)
+    w = _quantized(sw) if quantized else sw
+    registry.clear_history()
+    y = api.nm_matmul(x, w, epilogue=api.Epilogue(bias=bias,
+                                                  activation="relu"))
+    rec = registry.last_dispatch()
+    assert rec.impl.startswith("pallas_decode"), rec
+    assert rec.block[2] == block_k and rec.padded[1] == 2 * block_k, rec
+    np.testing.assert_array_equal(
+        np.asarray(y), np.asarray(_reference(x, w, bias, "relu")))
+
+
+@pytest.mark.parametrize("block_k", [64, 32], ids=["nk1", "nk2"])
+@pytest.mark.parametrize("cfg", CFGS + (NMConfig(1, 2), NMConfig(2, 8)),
+                         ids=lambda c: c.tag)
+def test_expand_x_matches_its_definition(cfg, block_k):
+    """x_m[k, j, r, c] == x[r, (kc // n) * m + j], kc = k * bkc + c,
+    element by element."""
+    from repro.kernels.indexmac.decode_kernel import expand_x
+
+    rows, k = 8, 64
+    x = np.arange(rows * k, dtype=np.float32).reshape(rows, k)
+    xm = np.asarray(expand_x(jnp.asarray(x), cfg.n, cfg.m, block_k))
+    bkc = block_k * cfg.n // cfg.m
+    assert xm.shape == (k // block_k, cfg.m, rows, bkc)
+    want = np.empty_like(xm)
+    for kb in range(k // block_k):
+        for j in range(cfg.m):
+            for r in range(rows):
+                for c in range(bkc):
+                    kc = kb * bkc + c
+                    want[kb, j, r, c] = x[r, (kc // cfg.n) * cfg.m + j]
+    np.testing.assert_array_equal(xm, want)
+
+
+def test_explain_dispatch_reports_the_mxu_dtype():
+    """A bf16 2:4 decode call takes bf16 MXU operands, an f32 one f32;
+    the dry run and the real call's record agree, and routes that run no
+    decode kernel report none."""
+    cfg = NMConfig(2, 4)
+    for dtype, mxu in ((jnp.bfloat16, "bfloat16"), (jnp.float32, "float32")):
+        x, sw, _ = _int_operands(cfg, 8, seed=47, dtype=dtype)
+        rec = api.explain_dispatch(x.shape, sw, dtype=dtype)
+        assert (rec.op, rec.mxu_dtype) == ("nm_matmul_decode", mxu), rec
+        registry.clear_history()
+        api.nm_matmul(x, sw)
+        assert registry.last_dispatch().mxu_dtype == mxu
+    # f32 activations keep the f32 MXU path even for bf16 values
+    _, sw16, _ = _int_operands(cfg, 8, seed=47, dtype=jnp.bfloat16)
+    assert api.explain_dispatch((8, K), sw16,
+                                dtype=jnp.float32).mxu_dtype == "float32"
+    assert api.explain_dispatch((64, K), sw16).mxu_dtype is None
+    off = dataclasses.replace(sw16, kernel_policy=api.KernelPolicy("off"))
+    rec = api.explain_dispatch((8, K), off)
+    assert (rec.impl, rec.mxu_dtype) == ("reference_decode", None), rec
 
 
 @pytest.mark.parametrize("activation",

@@ -8,6 +8,12 @@ about results or speed. The topology is described only inside a fixture,
 so collecting this file never loads the TPU library.
 """
 import dataclasses
+import math
+import os
+import pathlib
+import re
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -57,12 +63,54 @@ def chip_compile(monkeypatch):
     jax.config.update("jax_enable_compilation_cache", was)
 
 
+_ONE_GRID_STEP = """
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+from repro.core.sparsity import NMConfig
+from repro.kernels.indexmac.decode_kernel import nm_spmm_pallas_decode
+
+dev = topologies.get_topology_desc(platform="tpu",
+                                   topology_name="v5e:2x2").devices[0]
+sds = lambda s, d: jax.ShapeDtypeStruct(s, d,
+                                        sharding=SingleDeviceSharding(dev))
+jax.jit(lambda x, v, i: nm_spmm_pallas_decode(
+    x, v, i, cfg=NMConfig(2, 4), block_n=256, block_k=1024)).lower(
+    sds((8, 1024), jnp.bfloat16), sds((512, 256), jnp.bfloat16),
+    sds((512, 256), jnp.int8)).compile()
+"""
+
+
+def test_decode_grid_step_stays_small_for_v5e(tmp_path):
+    """One (8, 256, 1024) 2:4 bf16 grid step of the decode kernel lowers
+    to a few thousand LLO operations (the compressed-domain loop; a
+    dense expansion of the tile took about 21,000). Counted in Mosaic's
+    post-finalize-llo dump, in a subprocess so the dump flag reaches the
+    TPU library when it loads. It is the file's first test: one process
+    at a time may load that library, and the ``topo`` fixture keeps it
+    loaded in this one from its first use on."""
+    env = dict(os.environ)
+    env["LIBTPU_INIT_ARGS"] = (env.get("LIBTPU_INIT_ARGS", "")
+                               + f" --xla_mosaic_dump_to={tmp_path}")
+    src = pathlib.Path(__file__).resolve().parents[1] / "src"
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (str(src), env.get("PYTHONPATH")) if p)
+    done = subprocess.run([sys.executable, "-c", _ONE_GRID_STEP], env=env,
+                          capture_output=True, text=True, timeout=600)
+    dumps = sorted(tmp_path.glob("*_decode_kernel-post-finalize-llo.txt"))
+    if not dumps:  # the compile itself is guarded by the tests below
+        why = (done.stderr.strip().splitlines() or ["no error"])[-1]
+        pytest.skip(f"no Mosaic LLO dump was written ({why[:300]})")
+    text = dumps[-1].read_text()
+    ops = len(re.findall(r'^\s*(?:%[^=\n]+= )?"?llo\.', text, re.M))
+    assert 0 < ops < 5000, ops
+
+
 def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _weight(k, n, kind, sharding):
-    nm = NMConfig(2, 4)
+def _weight(k, n, kind, sharding, nm=NMConfig(2, 4)):
     kc = k * nm.n // nm.m
     idx = _sds((kc, n), jnp.int8, sharding)
     pol = KernelPolicy("auto")
@@ -88,13 +136,37 @@ def test_kernel_compiles_for_v5e(one_chip, chip_compile, kind, m, k, n):
     rec = registry.last_dispatch()
     assert rec.impl.startswith("pallas") and not rec.interpret, rec
     assert ("decode" in rec.op) == (m == 8), rec
+    assert rec.mxu_dtype == ("bfloat16" if m == 8 else None), rec
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     if rec.padded == rec.shape:
         # the kernel reads the compressed pair in its stored layout: no
-        # per-call relayout of the weight on the memory-bound path
-        assert not any(op in text for op in ("transpose(", "copy(",
-                                             "copy-start(", "pad(")), text
+        # per-call relayout of the weight on the memory-bound path. The
+        # prefill program relayouts nothing; the decode program only x,
+        # into its (m, M, K*n/m) expansion.
+        limit = 0 if m > 8 else w.nm.n * m * k
+        for line in text.splitlines():
+            if any(op in line for op in ("transpose(", "copy(",
+                                         "copy-start(", "pad(")):
+                dims = re.search(r"= \(?\w+\[([\d,]*)\]", line).group(1)
+                size = math.prod(int(d) for d in dims.split(",") if d)
+                assert size <= limit, line
+
+
+# the patterns beside 2:4 take the same compressed-domain loop
+@pytest.mark.parametrize("nm", [NMConfig(1, 4), NMConfig(4, 8)],
+                         ids=lambda c: c.tag)
+@pytest.mark.parametrize("k,n", [(4096, 11008), (11008, 4096)],
+                         ids=["w_up", "w_down"])
+def test_decode_kernel_compiles_per_pattern(one_chip, chip_compile, nm, k, n):
+    w = _weight(k, n, "bf16", one_chip, nm)
+    x = _sds((8, k), jnp.bfloat16, one_chip)
+    registry.clear_history()
+    compiled = jax.jit(api.nm_matmul).lower(x, w).compile()
+    rec = registry.last_dispatch()
+    assert rec.impl == "pallas_decode" and not rec.interpret, rec
+    assert rec.mxu_dtype == "bfloat16", rec
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def _compile_yi_decode_step(one_chip):
